@@ -241,6 +241,9 @@ def parse_args(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     args = parse_args()
     if args.smoke:
         smoke()

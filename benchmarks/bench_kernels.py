@@ -133,4 +133,7 @@ def parse_args(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run(parse_args())

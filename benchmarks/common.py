@@ -176,8 +176,9 @@ def out_path(name: str) -> str:
 
 def provenance() -> dict:
     """Reproducibility block attached to every bench artifact: where and
-    when the numbers came from.  Every probe is guarded — a missing git
-    checkout or jax install degrades to ``None``, never an exception."""
+    when the numbers came from, and the device as JAX reports it
+    (``platform``, ``device_kind``, ``devices`` count).  A missing git
+    checkout degrades ``git_sha`` to ``None``; the device probe raises."""
     import datetime
     import subprocess
 
@@ -188,20 +189,16 @@ def provenance() -> dict:
         ).stdout.strip() or None
     except (OSError, subprocess.SubprocessError):
         sha = None
-    try:
-        import jax
-
-        jax_version = jax.__version__
-        devices = jax.device_count()
-    except Exception:
-        jax_version, devices = None, None
+    dev = jax.devices()[0]
     return {
         "git_sha": sha,
         "timestamp_utc": datetime.datetime.now(
             datetime.timezone.utc).isoformat(timespec="seconds"),
         "numpy": np.__version__,
-        "jax": jax_version,
-        "devices": devices,
+        "jax": jax.__version__,
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "devices": jax.device_count(),
     }
 
 

@@ -28,7 +28,9 @@ def main() -> None:
         bench_tiers,
     )
     from benchmarks.common import build_stack
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     t0 = time.time()
     build_stack()  # train/cache the two-tier stack once
     results = {}

@@ -18,7 +18,9 @@ def main():
     from repro.core.calibration import IsotonicCalibrator, PlattCalibrator, ece, mce
     from repro.core.netsim import mbps, png_size_model
     from repro.policy import Env, Frame, make_policy
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     stack = build_stack()
     conf, correct = stack.calib["conf"], stack.calib["correct"]
     n = len(conf) // 2

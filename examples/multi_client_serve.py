@@ -72,8 +72,10 @@ def main():
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
     from repro.core.netsim import Uplink, mbps
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serving import FairScheduler, MultiStreamServer, ServeConfig
 
+    enable_compile_cache()
     if args.synthetic:
         from benchmarks.bench_multistream import synthetic_cfg, synthetic_streams, synthetic_tiers
 

@@ -32,7 +32,9 @@ def main():
     from repro.models import api
     from repro.models.transformer import ParallelPlan
     from repro.serving.engine import CascadeServer, ServeConfig
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     stack = build_stack()
     fh = api.build(FAST_CFG, ParallelPlan(remat=False))
     sh = api.build(SLOW_CFG, ParallelPlan(remat=False))

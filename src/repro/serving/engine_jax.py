@@ -31,6 +31,7 @@ a no-op and the engine runs identically on one CPU.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import NamedTuple, Optional
 
@@ -43,7 +44,7 @@ from repro.core.netsim import _FIXED_POINT_SWEEPS
 from repro.policy.fleet_jax import (PaddedFleet, PlannerSpec, PlanOut,
                                     clear_fleet, consume_fleet, ewma_fold,
                                     extend_fleet, plan_fleet, prune_fleet)
-from repro.sharding.axes import shard
+from repro.sharding.axes import current_mesh, shard
 
 __all__ = ["EngineSpec", "EngineGroup", "EngineParams", "RoundInputs",
            "EngineCarry", "RoundTrace", "init_carry", "make_engine",
@@ -778,12 +779,20 @@ def make_engine(spec: EngineSpec):
     return jax.jit(run, donate_argnums=(1,))
 
 
+@functools.lru_cache(maxsize=16)
+def _cached_engine(spec: EngineSpec, mesh):
+    """``make_engine(spec)`` built once per (spec, mesh), so a server's
+    later ``process_streams`` calls reuse the compiled scan.  The mesh is
+    part of the key because ``shard`` reads it while tracing."""
+    return make_engine(spec)
+
+
 def simulate(spec: EngineSpec, params: EngineParams, inputs: RoundInputs,
              carry: Optional[EngineCarry] = None):
     """One-shot convenience: init carry (unless given), run the scan."""
     if carry is None:
         carry = init_carry(spec, params)
-    return make_engine(spec)(params, carry, inputs)
+    return _cached_engine(spec, current_mesh())(params, carry, inputs)
 
 
 # --------------------------------------------------------------------------- #

@@ -19,8 +19,9 @@ import json
 import jax
 from repro.launch.cells import build_cell, lower_cell
 from repro.launch import roofline as rl
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 out = {}
 for arch, shape in [("vit-s16", "serve_b128"), ("qwen1.5-32b", "train_4k")]:
     import dataclasses

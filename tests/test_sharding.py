@@ -62,7 +62,9 @@ def test_shard_is_noop_without_mesh():
 
 
 def test_shard_applies_constraint_on_mesh():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1, 1), ("data", "model"))
     with sharding_ctx(mesh):
         @jax.jit
         def f(x):
