@@ -208,21 +208,12 @@ def emit_bench_json(name: str, payload: dict, *, mirror: str = None) -> str:
     Every ``BENCH_*.json`` goes through here so the artifacts share one
     serialization policy (indent=2, trailing newline, numpy scalars coerced
     to plain floats) and one ``provenance`` block (git sha, UTC timestamp,
-    library versions, device count).  When the process-wide default phase
-    profiler (``repro.obs.profile.DEFAULT``) holds samples, its summary is
-    attached under ``"profile"``.  ``mirror`` writes the same payload under
+    library versions, device count).  ``mirror`` writes the same payload under
     a second name — used by benches that keep a legacy filename alongside
     the canonical ``BENCH_*`` one.  Returns the primary path.
     """
     payload = dict(payload)
     payload.setdefault("provenance", provenance())
-    try:
-        from repro.obs.profile import DEFAULT
-
-        if DEFAULT and "profile" not in payload:
-            payload["profile"] = DEFAULT.summarize()
-    except ImportError:
-        pass
     path = out_path(name)
     for p in (path,) + ((out_path(mirror),) if mirror else ()):
         with open(p, "w") as f:

@@ -12,9 +12,10 @@ skip every hook).  Three parts (docs/observability.md):
   * ``trace.FrameTracer`` — per-escalation lifecycle spans with
     cell/replica/batch ids, exported as Chrome trace-event / Perfetto
     JSON (numpy engine only);
-  * ``profile.PhaseProfiler`` — wall-clock phase breakdown (plan /
-    serve / transmit / fold) plus the AOT compile-vs-steady split for
-    jitted entry points.
+  * ``profile.PhaseProfiler`` — the program's spans (wall-clock phase
+    totals, and ``repro.*`` annotations on the device trace's clock) and
+    counters, plus the AOT compile-vs-steady split for jitted entry
+    points.
 
 ``Telemetry`` is the bundle the engines consume: pick the parts with
 flags, the server binds dimensions at construction.
@@ -24,12 +25,12 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional
 
-from repro.obs.profile import DEFAULT, PhaseProfiler, aot_split
+from repro.obs.profile import PhaseProfiler, aot_split
 from repro.obs.timeseries import FleetRecorder, relock_lags
 from repro.obs.trace import FrameTracer, export_chrome_trace
 
 __all__ = ["Telemetry", "FleetRecorder", "FrameTracer", "PhaseProfiler",
-           "aot_split", "export_chrome_trace", "relock_lags", "DEFAULT"]
+           "aot_split", "export_chrome_trace", "relock_lags"]
 
 
 @dataclass

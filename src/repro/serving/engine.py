@@ -43,7 +43,6 @@ checks this).
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import Callable, Optional
 
@@ -54,6 +53,7 @@ import jax.numpy as jnp
 from repro.core.cascade import cascade_classify, fast_pass, slow_pass_multires
 from repro.core.netsim import Uplink, payload_sizes, png_size_model, transfer_seconds
 from repro.net import EdgeFabric
+from repro.obs.profile import phase
 from repro.policy import BandwidthEstimator, FleetRunner, PolicyRunner, resolve_policies
 from repro.serving.events import ArrivalSchedule, EscalationBatch, select_escalations
 from repro.serving.metrics import AggregateMetrics, ServeMetrics
@@ -295,8 +295,9 @@ class MultiStreamServer:
         if self.backend == "jax":
             return self._process_streams_jax(frames, labels, schedule)
 
-        # telemetry hooks: every guard below is a plain ``is not None`` so
-        # the default (no telemetry) path touches no clock and no buffer
+        # telemetry hooks: every guard below is a plain ``is not None`` (or
+        # ``phase``, which is one) so the default (no telemetry) path opens
+        # no span, touches no clock and no buffer
         tel = self.telemetry
         rec = tel.recorder if tel is not None else None
         tracer = tel.tracer if tel is not None else None
@@ -309,13 +310,14 @@ class MultiStreamServer:
             # yet joined — the latter have nothing to clear)
             self.fleet.retire(~active)
 
-            t0 = time.perf_counter() if prof is not None else 0.0
-            flat = jnp.asarray(frames[:, start : start + b].reshape(S * b, *frames.shape[2:]))
-            fp, cf = _fast_pass(cfg, self.fast_forward, self.calibrate, flat)
-            fast_preds = np.asarray(fp).reshape(S, b)
-            conf = np.asarray(cf).reshape(S, b)
+            with phase(prof, "serve"):
+                host = frames[:, start : start + b].reshape(S * b, *frames.shape[2:])
+                flat = jnp.asarray(host)
+                fp, cf = _fast_pass(cfg, self.fast_forward, self.calibrate, flat)
+                fast_preds = np.asarray(fp).reshape(S, b)
+                conf = np.asarray(cf).reshape(S, b)
             if prof is not None:
-                prof.add("serve", time.perf_counter() - t0)
+                prof.count("h2d_bytes", host.nbytes)
             t_ready = arr + t_fast  # (S, b); +inf on invalid slots
 
             # control plane: one batched plan over every active backlog,
@@ -357,30 +359,29 @@ class MultiStreamServer:
             )
 
             # one batched slow-tier call for every stream's escalations
-            t0 = time.perf_counter() if prof is not None else 0.0
-            if len(esc):
-                gathered = jnp.take(flat, jnp.asarray(s_idx * b + slot_idx), axis=0)
-                slow_preds = np.asarray(slow_pass_multires(self.slow_forward, gathered, esc.res))
-            else:
-                slow_preds = np.zeros(0, dtype=fast_preds.dtype)
+            with phase(prof, "serve"):
+                if len(esc):
+                    gathered = jnp.take(flat, jnp.asarray(s_idx * b + slot_idx), axis=0)
+                    slow_preds = np.asarray(slow_pass_multires(self.slow_forward, gathered,
+                                                               esc.res))
+                else:
+                    slow_preds = np.zeros(0, dtype=fast_preds.dtype)
             if prof is not None:
-                prof.add("serve", time.perf_counter() - t0)
+                prof.count("slow_frames", len(esc))
 
             # fair uplink schedule (cost normalized by each stream's own
             # cell rate), then one fabric transmit for the round: per-cell
             # uplink queues + replica placement + pool service
-            t0 = time.perf_counter() if prof is not None else 0.0
-            order = self.scheduler.order(esc.stream, esc.t_ready,
-                                         cost=esc.payload / self._stream_bw[esc.stream])
-            q = esc.permuted(order)
-            slow_q = slow_preds[order]
-            # split suffixes cost a fraction of the full-model service time
-            # (frames scale by exactly 1.0 — a float no-op)
-            lands = self.fabric.transmit(q.stream, q.payload, q.t_ready,
-                                         service_scale=act.srv_frac[res_idx[q.stream]],
-                                         collect_detail=tracer is not None)
-            if prof is not None:
-                prof.add("transmit", time.perf_counter() - t0)
+            with phase(prof, "transmit"):
+                order = self.scheduler.order(esc.stream, esc.t_ready,
+                                             cost=esc.payload / self._stream_bw[esc.stream])
+                q = esc.permuted(order)
+                slow_q = slow_preds[order]
+                # split suffixes cost a fraction of the full-model service
+                # time (frames scale by exactly 1.0 — a float no-op)
+                lands = self.fabric.transmit(q.stream, q.payload, q.t_ready,
+                                             service_scale=act.srv_frac[res_idx[q.stream]],
+                                             collect_detail=tracer is not None)
             ok = lands <= arr[q.stream, q.slot] + cfg.deadline
 
             if tracer is not None and len(q):
@@ -393,44 +394,42 @@ class MultiStreamServer:
                     batch_id=d["batch_id"], done=d["done"],
                     land=lands, ok=ok, deadline=cfg.deadline)
 
-            t0 = time.perf_counter() if prof is not None else 0.0
-            final = fast_preds.copy()
-            final[q.stream[ok], q.slot[ok]] = slow_q[ok]
+            with phase(prof, "fold"):
+                final = fast_preds.copy()
+                final[q.stream[ok], q.slot[ok]] = slow_q[ok]
 
-            # batched per-stream bandwidth observations (transmission order):
-            # each reply's *actual* service time is subtracted (servers
-            # report their processing time, so heterogeneous replicas do
-            # not skew the estimate), but replica *queueing* is not — the
-            # device cannot separate queueing from wire time, so slow-tier
-            # contention surfaces to the EWMAs as reduced effective
-            # bandwidth and the policies back off
-            self.fleet.observe_bandwidth(
-                q.stream, q.payload,
-                transfer_seconds(lands, q.t_ready, latency=self.fabric.latency,
-                                 server_time=self.fabric.last_service_time))
+                # batched per-stream bandwidth observations (transmission order):
+                # each reply's *actual* service time is subtracted (servers
+                # report their processing time, so heterogeneous replicas do
+                # not skew the estimate), but replica *queueing* is not — the
+                # device cannot separate queueing from wire time, so slow-tier
+                # contention surfaces to the EWMAs as reduced effective
+                # bandwidth and the policies back off
+                self.fleet.observe_bandwidth(
+                    q.stream, q.payload,
+                    transfer_seconds(lands, q.t_ready, latency=self.fabric.latency,
+                                     server_time=self.fabric.last_service_time))
 
-            # backlog bookkeeping, batched (same semantics as CascadeServer):
-            # planned offloads left the device; non-escalated valid frames
-            # join their stream's backlog in slot order
-            self.fleet.consume(batch)
-            esc_mask = np.zeros((S, b), dtype=bool)
-            esc_mask[s_idx, slot_idx] = True
-            add = valid & ~esc_mask
-            add_s, _ = np.nonzero(add)
-            self.fleet.observe_frames(add_s, arr[add], conf[add].astype(np.float64))
+                # backlog bookkeeping, batched (same semantics as CascadeServer):
+                # planned offloads left the device; non-escalated valid frames
+                # join their stream's backlog in slot order
+                self.fleet.consume(batch)
+                esc_mask = np.zeros((S, b), dtype=bool)
+                esc_mask[s_idx, slot_idx] = True
+                add = valid & ~esc_mask
+                add_s, _ = np.nonzero(add)
+                self.fleet.observe_frames(add_s, arr[add], conf[add].astype(np.float64))
 
-            # vectorized metrics: latency per frame, counts per stream
-            lat = np.full((S, b), t_fast)
-            lat[q.stream[ok], q.slot[ok]] = lands[ok] - arr[q.stream[ok], q.slot[ok]]
-            lat[q.stream[~ok], q.slot[~ok]] = cfg.deadline
-            off_counts = np.bincount(q.stream[ok], minlength=S)
-            miss_counts = np.bincount(q.stream[~ok], minlength=S)
-            correct = (((final == labels[:, start : start + b]) & valid).sum(axis=1)
-                       if labels is not None else np.zeros(S, dtype=np.int64))
-            self.metrics.update_round(valid.sum(axis=1), off_counts, miss_counts,
-                                      correct, lat, valid)
-            if prof is not None:
-                prof.add("fold", time.perf_counter() - t0)
+                # vectorized metrics: latency per frame, counts per stream
+                lat = np.full((S, b), t_fast)
+                lat[q.stream[ok], q.slot[ok]] = lands[ok] - arr[q.stream[ok], q.slot[ok]]
+                lat[q.stream[~ok], q.slot[~ok]] = cfg.deadline
+                off_counts = np.bincount(q.stream[ok], minlength=S)
+                miss_counts = np.bincount(q.stream[~ok], minlength=S)
+                correct = (((final == labels[:, start : start + b]) & valid).sum(axis=1)
+                           if labels is not None else np.zeros(S, dtype=np.int64))
+                self.metrics.update_round(valid.sum(axis=1), off_counts, miss_counts,
+                                          correct, lat, valid)
 
             if rec is not None:
                 # cumulative counters (the metrics SoA is exactly the jax
@@ -479,7 +478,15 @@ class MultiStreamServer:
         """Compiled backend: precompute the neural tiers per round on the
         host, then advance the whole replay as one jitted ``lax.scan``
         (``serving/engine_jax.py``).  Decision/schedule semantics are pinned
-        to the numpy path by ``tests/test_fleet_jax.py``."""
+        to the numpy path by ``tests/test_fleet_jax.py``.
+
+        Under ``Telemetry(profile=True)`` every host step of the body runs
+        inside a ``repro.*`` span (docs/observability.md): ``prepare``,
+        ``precompute`` (per round ``upload``, ``tier_fast``, one
+        ``tier_slow`` per rung, ``host_read`` around each device-to-host
+        read, ``pad``), ``scan``, ``fold`` and ``record``; the counters
+        ``slow_frames`` and ``h2d_bytes`` count the slow tier's forwards
+        and the frame bytes sent to the device."""
         import jax.numpy as jnp
 
         from repro.serving import engine_jax as ej
@@ -493,69 +500,76 @@ class MultiStreamServer:
         tel = self.telemetry
         rec = tel.recorder if tel is not None else None
         prof = tel.profiler if tel is not None else None
-        # under a mesh, pad the stream axis to the device multiple so the
-        # "streams" logical axis actually splits; the pad rows never see a
-        # valid frame, so every output below is sliced back to [:S]
-        mult = logical_axis_multiple("streams")
-        S_pad = -(-S // mult) * mult
-        spad = S_pad - S
-        spec = ej.spec_from_server(self, collect=collect, pad_streams=S_pad,
-                                   telemetry=rec is not None)
-        params = ej.params_from_server(self, spec)
+        with phase(prof, "prepare"):
+            # under a mesh, pad the stream axis to the device multiple so
+            # the "streams" logical axis actually splits; the pad rows never
+            # see a valid frame, so every output below is sliced back to [:S]
+            mult = logical_axis_multiple("streams")
+            S_pad = -(-S // mult) * mult
+            spad = S_pad - S
+            spec = ej.spec_from_server(self, collect=collect, pad_streams=S_pad,
+                                       telemetry=rec is not None)
+            params = ej.params_from_server(self, spec)
 
         # host precompute: confidences + per-resolution slow-tier
         # correctness for every (frame, res) — both tiers are deterministic
         # per frame, so this equals the numpy path's escalated-only batching
-        t0 = time.perf_counter() if prof is not None else 0.0
         rounds = []
         per_round = []
-        for start, arr, valid in schedule.rounds(B):
-            b = arr.shape[1]
-            flat = jnp.asarray(frames[:, start : start + b].reshape(
-                S * b, *frames.shape[2:]))
-            fp, cf = _fast_pass(cfg, self.fast_forward, self.calibrate, flat)
-            fast_preds = np.asarray(fp).reshape(S, b)
-            conf = np.asarray(cf).reshape(S, b)
-            lab = labels[:, start : start + b] if labels is not None else None
-            fast_ok = (fast_preds == lab) if lab is not None else np.zeros((S, b), bool)
-            slow_ok = np.zeros((S, b, m), dtype=bool)
-            if lab is not None:
-                for r in range(m):
-                    sp = np.asarray(slow_pass_multires(
-                        self.slow_forward, flat,
-                        np.full(S * b, resolutions[r]))).reshape(S, b)
-                    slow_ok[:, :, r] = sp == lab
-            pad = B - b
-            if pad:
-                arr = np.pad(arr, ((0, 0), (0, pad)), constant_values=np.inf)
-                valid = np.pad(valid, ((0, 0), (0, pad)))
-                conf = np.pad(conf, ((0, 0), (0, pad)), constant_values=np.inf)
-                fast_ok = np.pad(fast_ok, ((0, 0), (0, pad)))
-                slow_ok = np.pad(slow_ok, ((0, 0), (0, pad), (0, 0)))
-            if spad:
-                arr = np.pad(arr, ((0, spad), (0, 0)), constant_values=np.inf)
-                valid = np.pad(valid, ((0, spad), (0, 0)))
-                conf = np.pad(conf, ((0, spad), (0, 0)), constant_values=np.inf)
-                fast_ok = np.pad(fast_ok, ((0, spad), (0, 0)))
-                slow_ok = np.pad(slow_ok, ((0, spad), (0, 0), (0, 0)))
-            rounds.append((arr, valid, conf, fast_ok, slow_ok))
-            per_round.append((start, b))
-        if prof is not None:
-            prof.add("precompute", time.perf_counter() - t0)
+        with phase(prof, "precompute"):
+            for start, arr, valid in schedule.rounds(B):
+                b = arr.shape[1]
+                with phase(prof, "upload"):
+                    host = frames[:, start : start + b].reshape(S * b, *frames.shape[2:])
+                    flat = jnp.asarray(host)
+                with phase(prof, "tier_fast"):
+                    fp, cf = _fast_pass(cfg, self.fast_forward, self.calibrate, flat)
+                with phase(prof, "host_read"):
+                    fast_preds = np.asarray(fp).reshape(S, b)
+                    conf = np.asarray(cf).reshape(S, b)
+                lab = labels[:, start : start + b] if labels is not None else None
+                fast_ok = (fast_preds == lab) if lab is not None else np.zeros((S, b), bool)
+                slow_ok = np.zeros((S, b, m), dtype=bool)
+                if lab is not None:
+                    for r in range(m):
+                        with phase(prof, "tier_slow"):
+                            sp = slow_pass_multires(self.slow_forward, flat,
+                                                    np.full(S * b, resolutions[r]))
+                        with phase(prof, "host_read"):
+                            slow_ok[:, :, r] = np.asarray(sp).reshape(S, b) == lab
+                if prof is not None:
+                    prof.count("h2d_bytes", host.nbytes)
+                    if lab is not None:
+                        prof.count("slow_frames", S * b * m)
+                with phase(prof, "pad"):
+                    pad = B - b
+                    if pad:
+                        arr = np.pad(arr, ((0, 0), (0, pad)), constant_values=np.inf)
+                        valid = np.pad(valid, ((0, 0), (0, pad)))
+                        conf = np.pad(conf, ((0, 0), (0, pad)), constant_values=np.inf)
+                        fast_ok = np.pad(fast_ok, ((0, 0), (0, pad)))
+                        slow_ok = np.pad(slow_ok, ((0, 0), (0, pad), (0, 0)))
+                    if spad:
+                        arr = np.pad(arr, ((0, spad), (0, 0)), constant_values=np.inf)
+                        valid = np.pad(valid, ((0, spad), (0, 0)))
+                        conf = np.pad(conf, ((0, spad), (0, 0)), constant_values=np.inf)
+                        fast_ok = np.pad(fast_ok, ((0, spad), (0, 0)))
+                        slow_ok = np.pad(slow_ok, ((0, spad), (0, 0), (0, 0)))
+                rounds.append((arr, valid, conf, fast_ok, slow_ok))
+                per_round.append((start, b))
         if not rounds:
             return self.metrics
         # place the stacked (R, S, B[, m]) inputs pre-split over the mesh
         # (no-op off-mesh) so the scan reads local shards from round one
-        t0 = time.perf_counter() if prof is not None else 0.0
-        inputs = ej.RoundInputs(*(
-            host_shard(jnp.asarray(col), *((None, "streams", None, None)[:col.ndim]))
-            for col in (np.stack(c) for c in zip(*rounds))))
-        carry, ys = ej.simulate(spec, params, inputs)
-        if prof is not None:
-            import jax
+        with phase(prof, "scan"):
+            inputs = ej.RoundInputs(*(
+                host_shard(jnp.asarray(col), *((None, "streams", None, None)[:col.ndim]))
+                for col in (np.stack(c) for c in zip(*rounds))))
+            carry, ys = ej.simulate(spec, params, inputs)
+            if prof is not None:
+                import jax
 
-            jax.block_until_ready(carry)
-            prof.add("scan", time.perf_counter() - t0)
+                jax.block_until_ready(carry)
         if carry.fp_bad is not None and bool(carry.fp_bad):
             import warnings
 
@@ -566,54 +580,52 @@ class MultiStreamServer:
 
         # fold per-round counters/latencies into the same AggregateMetrics
         # (everything stream-indexed is sliced back to the real S rows)
-        t0 = time.perf_counter() if prof is not None else 0.0
-        # host baselines of the cumulative second counters — the carry
-        # accumulates deltas from zero, the recorder (and numpy) report
-        # absolute values, so the pre-scan state is added back per round
-        base_cb = np.asarray([c.uplink.busy_seconds for c in self.fabric.cells])
-        base_cq = np.asarray([c.uplink.queued_seconds for c in self.fabric.cells])
-        base_rb = self.fabric.pool.busy_seconds.copy()
-        base_rq = self.fabric.pool.queued_seconds.copy()
-        base_ctr = (self.metrics._frames.copy(), self.metrics._offloaded.copy(),
-                    self.metrics._missed.copy(), self.metrics._correct.copy())
-        off = np.asarray(ys.off_counts)[:, :S]
-        miss = np.asarray(ys.miss_counts)[:, :S]
-        corr = np.asarray(ys.correct)[:, :S]
-        lat = np.asarray(ys.lat, dtype=np.float64)[:, :S]
-        for i, (start, b) in enumerate(per_round):
-            valid_i = rounds[i][1][:S, :b]
-            self.metrics.update_round(valid_i.sum(axis=1), off[i], miss[i],
-                                      corr[i], lat[i][:, :b], valid_i)
+        with phase(prof, "fold"):
+            # host baselines of the cumulative second counters — the carry
+            # accumulates deltas from zero, the recorder (and numpy) report
+            # absolute values, so the pre-scan state is added back per round
+            base_cb = np.asarray([c.uplink.busy_seconds for c in self.fabric.cells])
+            base_cq = np.asarray([c.uplink.queued_seconds for c in self.fabric.cells])
+            base_rb = self.fabric.pool.busy_seconds.copy()
+            base_rq = self.fabric.pool.queued_seconds.copy()
+            base_ctr = (self.metrics._frames.copy(), self.metrics._offloaded.copy(),
+                        self.metrics._missed.copy(), self.metrics._correct.copy())
+            off = np.asarray(ys.off_counts)[:, :S]
+            miss = np.asarray(ys.miss_counts)[:, :S]
+            corr = np.asarray(ys.correct)[:, :S]
+            lat = np.asarray(ys.lat, dtype=np.float64)[:, :S]
+            for i, (start, b) in enumerate(per_round):
+                valid_i = rounds[i][1][:S, :b]
+                self.metrics.update_round(valid_i.sum(axis=1), off[i], miss[i],
+                                          corr[i], lat[i][:, :b], valid_i)
 
-        # fold device state back into the host objects so summaries,
-        # contention counters and follow-on numpy rounds stay correct
-        for c, cell in enumerate(self.fabric.cells):
-            cell.uplink._busy_until = float(carry.cell_busy[c])
-            cell.uplink.n_transfers += int(carry.cell_n[c])
-            cell.uplink.busy_seconds += float(carry.cell_busy_s[c])
-            cell.uplink.queued_seconds += float(carry.cell_queued_s[c])
-        pool = self.fabric.pool
-        pool.busy_until[:] = np.asarray(carry.rep_busy, dtype=np.float64)
-        pool.n_jobs += np.asarray(carry.rep_n, dtype=np.int64)
-        pool.busy_seconds += np.asarray(carry.rep_busy_s, dtype=np.float64)
-        pool.queued_seconds += np.asarray(carry.rep_queued_s, dtype=np.float64)
-        pool.avg_batch = float(carry.avg_batch)  # occupancy EWMA (1.0 = serial)
-        self.fabric.placement._next = int(carry.rr_next)
-        self.fleet.bw_est[:] = np.asarray(carry.bw_est, dtype=np.float64)[:S]
-        from repro.policy.fleet_jax import unpad_fleet
+            # fold device state back into the host objects so summaries,
+            # contention counters and follow-on numpy rounds stay correct
+            for c, cell in enumerate(self.fabric.cells):
+                cell.uplink._busy_until = float(carry.cell_busy[c])
+                cell.uplink.n_transfers += int(carry.cell_n[c])
+                cell.uplink.busy_seconds += float(carry.cell_busy_s[c])
+                cell.uplink.queued_seconds += float(carry.cell_queued_s[c])
+            pool = self.fabric.pool
+            pool.busy_until[:] = np.asarray(carry.rep_busy, dtype=np.float64)
+            pool.n_jobs += np.asarray(carry.rep_n, dtype=np.int64)
+            pool.busy_seconds += np.asarray(carry.rep_busy_s, dtype=np.float64)
+            pool.queued_seconds += np.asarray(carry.rep_queued_s, dtype=np.float64)
+            pool.avg_batch = float(carry.avg_batch)  # occupancy EWMA (1.0 = serial)
+            self.fabric.placement._next = int(carry.rr_next)
+            self.fleet.bw_est[:] = np.asarray(carry.bw_est, dtype=np.float64)[:S]
+            from repro.policy.fleet_jax import unpad_fleet
 
-        fleet_c = carry.fleet
-        if spad:  # drop the inert pad rows (always empty backlogs)
-            fleet_c = type(fleet_c)(fleet_c.arrival[:S], fleet_c.conf[:S],
-                                    fleet_c.length[:S])
-        arr_f, conf_f, lens = unpad_fleet(fleet_c)
-        st = self.fleet.state
-        st.arrival = arr_f.astype(np.float64)
-        st.conf = conf_f.astype(np.float64)
-        st.stream_id = np.repeat(np.arange(S), lens)
-        st._rebuild_offsets()
-        if prof is not None:
-            prof.add("fold", time.perf_counter() - t0)
+            fleet_c = carry.fleet
+            if spad:  # drop the inert pad rows (always empty backlogs)
+                fleet_c = type(fleet_c)(fleet_c.arrival[:S], fleet_c.conf[:S],
+                                        fleet_c.length[:S])
+            arr_f, conf_f, lens = unpad_fleet(fleet_c)
+            st = self.fleet.state
+            st.arrival = arr_f.astype(np.float64)
+            st.conf = conf_f.astype(np.float64)
+            st.stream_id = np.repeat(np.arange(S), lens)
+            st._rebuild_offsets()
 
         if rec is not None:
             # replay the scan's stacked telemetry columns into the recorder.
@@ -622,31 +634,32 @@ class MultiStreamServer:
             # running SoA); t and bw_true are recomputed host-side from the
             # same float64 arrival grid, so they are bit-equal by
             # construction; the rest compares at the tolerance policy.
-            frames_c = base_ctr[0] + np.cumsum(
-                [r[1][:S].sum(axis=1) for r in rounds], axis=0)
-            off_c = base_ctr[1] + np.cumsum(off, axis=0, dtype=np.int64)
-            miss_c = base_ctr[2] + np.cumsum(miss, axis=0, dtype=np.int64)
-            corr_c = base_ctr[3] + np.cumsum(corr, axis=0, dtype=np.int64)
-            bw_ts = np.asarray(ys.ts_bw_est, dtype=np.float64)[:, :S]
-            hist_ts = np.asarray(ys.ts_off_hist, dtype=np.int64)
-            cb = base_cb + np.asarray(ys.ts_cell_busy_s, dtype=np.float64)
-            cq = base_cq + np.asarray(ys.ts_cell_queued_s, dtype=np.float64)
-            rb = base_rb + np.asarray(ys.ts_rep_busy_s, dtype=np.float64)
-            rq = base_rq + np.asarray(ys.ts_rep_queued_s, dtype=np.float64)
-            ab = np.asarray(ys.ts_avg_batch, dtype=np.float64)
-            st_ts = np.asarray(ys.ts_st_est, dtype=np.float64)
-            for i in range(len(per_round)):
-                arr_i = rounds[i][0][:S]
-                fin = arr_i[np.isfinite(arr_i)]
-                t_round = float(fin.min()) if len(fin) else np.nan
-                rec.record_round(
-                    t=t_round, frames=frames_c[i], offloads=off_c[i],
-                    misses=miss_c[i], correct=corr_c[i], bw_est=bw_ts[i],
-                    bw_true=self.fabric.true_bandwidth(t_round),
-                    cell_busy_s=cb[i], cell_queued_s=cq[i],
-                    rep_busy_s=rb[i], rep_queued_s=rq[i],
-                    avg_batch=ab[i], server_time=st_ts[i],
-                    action_off=hist_ts[i])
+            with phase(prof, "record"):
+                frames_c = base_ctr[0] + np.cumsum(
+                    [r[1][:S].sum(axis=1) for r in rounds], axis=0)
+                off_c = base_ctr[1] + np.cumsum(off, axis=0, dtype=np.int64)
+                miss_c = base_ctr[2] + np.cumsum(miss, axis=0, dtype=np.int64)
+                corr_c = base_ctr[3] + np.cumsum(corr, axis=0, dtype=np.int64)
+                bw_ts = np.asarray(ys.ts_bw_est, dtype=np.float64)[:, :S]
+                hist_ts = np.asarray(ys.ts_off_hist, dtype=np.int64)
+                cb = base_cb + np.asarray(ys.ts_cell_busy_s, dtype=np.float64)
+                cq = base_cq + np.asarray(ys.ts_cell_queued_s, dtype=np.float64)
+                rb = base_rb + np.asarray(ys.ts_rep_busy_s, dtype=np.float64)
+                rq = base_rq + np.asarray(ys.ts_rep_queued_s, dtype=np.float64)
+                ab = np.asarray(ys.ts_avg_batch, dtype=np.float64)
+                st_ts = np.asarray(ys.ts_st_est, dtype=np.float64)
+                for i in range(len(per_round)):
+                    arr_i = rounds[i][0][:S]
+                    fin = arr_i[np.isfinite(arr_i)]
+                    t_round = float(fin.min()) if len(fin) else np.nan
+                    rec.record_round(
+                        t=t_round, frames=frames_c[i], offloads=off_c[i],
+                        misses=miss_c[i], correct=corr_c[i], bw_est=bw_ts[i],
+                        bw_true=self.fabric.true_bandwidth(t_round),
+                        cell_busy_s=cb[i], cell_queued_s=cq[i],
+                        rep_busy_s=rb[i], rep_queued_s=rq[i],
+                        avg_batch=ab[i], server_time=st_ts[i],
+                        action_off=hist_ts[i])
 
         if self.round_hook is not None:
             act = self.fleet.action_table
@@ -678,3 +691,4 @@ class MultiStreamServer:
                     "inexact": np.asarray(ys.inexact[i])[:S],
                 })
         return self.metrics
+

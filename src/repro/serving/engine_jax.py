@@ -436,328 +436,344 @@ def _round_step(spec: EngineSpec, params: EngineParams,
     dt = spec.planner.dtype
     N = S * B
     inf = jnp.inf
-    arr = shard(x.arr.astype(dt), "streams", None)
-    valid, conf = x.valid, x.conf.astype(dt)
+
+    # Each numbered stage runs under its own ``round.<stage>`` name scope:
+    # the compiled round's instructions carry it in their metadata, so a
+    # device trace's ops map to stages (docs/observability.md).  Scopes
+    # change HLO metadata only, never the compiled ops.
 
     # (1) active streams; retire the rest (FleetRunner.retire)
-    active = valid.any(axis=1)
-    fleet = clear_fleet(carry.fleet, ~active)
+    with jax.named_scope("round.retire"):
+        arr = shard(x.arr.astype(dt), "streams", None)
+        valid, conf = x.valid, x.conf.astype(dt)
+        active = valid.any(axis=1)
+        fleet = clear_fleet(carry.fleet, ~active)
 
     # (2) control plane: prune + one batched plan (FleetRunner.plan_all);
     # heterogeneous fleets prune per group's policy and plan group by group
-    now = arr.min(axis=1)  # first valid arrival; +inf when none
-    if spec.groups:
-        g_prune, g_oneshot, g_mb = _group_flags(spec)
-        prune_mask = active & jnp.asarray(g_prune)
-    else:
-        prune_mask = active if spec.prune else jnp.zeros_like(active)
-    fleet = prune_fleet(fleet, now, spec.deadline, prune_mask)
-    fleet = PaddedFleet(shard(fleet.arrival, "streams", None),
-                        shard(fleet.conf, "streams", None),
-                        shard(fleet.length, "streams"))
-    bw_plan = jnp.maximum(carry.bw_est, 1.0)  # same dead-link floor
-    st_eff = None
-    if spec.batch_kind != "none":
-        # occupancy-calibrated T^o = f(expected_batch)/expected_batch at the
-        # observed occupancy EWMA (ReplicaPool.expected_server_time)
-        nb = jnp.maximum(carry.avg_batch, 1.0)
-        st_eff = (_batch_latency(spec, nb) / nb).astype(dt)
-    if spec.groups:
-        plan = _plan_groups(spec, fleet, now, bw_plan, st_eff)
-    elif st_eff is None:
-        plan = plan_fleet(spec.planner, fleet, now, bw_plan)
-    else:
-        plan = plan_fleet(spec.planner, fleet, now, bw_plan, st_eff)
-    theta = jnp.where(active, plan.theta, 0.0)
-    res_idx = jnp.where(active, plan.resolution, m - 1)
-    n_off = jnp.where(active, plan.n_offloads, 0)
-    dec = jnp.where(active[:, None], plan.dec, jnp.int8(-1))
-    cap = jnp.where(active, jnp.maximum(n_off, 1), 0)
+    with jax.named_scope("round.plan"):
+        now = arr.min(axis=1)  # first valid arrival; +inf when none
+        if spec.groups:
+            g_prune, g_oneshot, g_mb = _group_flags(spec)
+            prune_mask = active & jnp.asarray(g_prune)
+        else:
+            prune_mask = active if spec.prune else jnp.zeros_like(active)
+        fleet = prune_fleet(fleet, now, spec.deadline, prune_mask)
+        fleet = PaddedFleet(shard(fleet.arrival, "streams", None),
+                            shard(fleet.conf, "streams", None),
+                            shard(fleet.length, "streams"))
+        bw_plan = jnp.maximum(carry.bw_est, 1.0)  # same dead-link floor
+        st_eff = None
+        if spec.batch_kind != "none":
+            # occupancy-calibrated T^o = f(expected_batch)/expected_batch at the
+            # observed occupancy EWMA (ReplicaPool.expected_server_time)
+            nb = jnp.maximum(carry.avg_batch, 1.0)
+            st_eff = (_batch_latency(spec, nb) / nb).astype(dt)
+        if spec.groups:
+            plan = _plan_groups(spec, fleet, now, bw_plan, st_eff)
+        elif st_eff is None:
+            plan = plan_fleet(spec.planner, fleet, now, bw_plan)
+        else:
+            plan = plan_fleet(spec.planner, fleet, now, bw_plan, st_eff)
+        theta = jnp.where(active, plan.theta, 0.0)
+        res_idx = jnp.where(active, plan.resolution, m - 1)
+        n_off = jnp.where(active, plan.n_offloads, 0)
+        dec = jnp.where(active[:, None], plan.dec, jnp.int8(-1))
+        cap = jnp.where(active, jnp.maximum(n_off, 1), 0)
 
     # (3) escalation gate (select_escalations): per stream the cap lowest
     # confidences below theta — stable conf argsort + cumsum gate
-    conf_gate = jnp.where(valid, conf, inf)
-    o_slot = jnp.argsort(conf_gate, axis=1)
-    gate_sorted = jnp.take_along_axis(conf_gate < theta[:, None], o_slot, axis=1)
-    take_sorted = gate_sorted & (jnp.cumsum(gate_sorted, axis=1) <= cap[:, None])
-    esc = jnp.zeros((S, B), bool).at[
-        jnp.arange(S)[:, None], o_slot].set(take_sorted)
+    with jax.named_scope("round.gate"):
+        conf_gate = jnp.where(valid, conf, inf)
+        o_slot = jnp.argsort(conf_gate, axis=1)
+        gate_sorted = jnp.take_along_axis(conf_gate < theta[:, None], o_slot, axis=1)
+        take_sorted = gate_sorted & (jnp.cumsum(gate_sorted, axis=1) <= cap[:, None])
+        esc = jnp.zeros((S, B), bool).at[
+            jnp.arange(S)[:, None], o_slot].set(take_sorted)
 
-    payload_s = params.sizes[res_idx].astype(dt)  # (S,) planned upload bytes
-    t_ready = arr + spec.t_fast
-    if spec.has_splits:
-        # a split action's upload leaves the device only after the model
-        # prefix runs — shifts SFQ readiness AND the wire submit below
-        t_dev_s = jnp.asarray(spec.act_t_dev, dtype=dt)[res_idx]  # (S,)
-        t_ready = t_ready + t_dev_s[:, None]
+        payload_s = params.sizes[res_idx].astype(dt)  # (S,) planned upload bytes
+        t_ready = arr + spec.t_fast
+        if spec.has_splits:
+            # a split action's upload leaves the device only after the model
+            # prefix runs — shifts SFQ readiness AND the wire submit below
+            t_dev_s = jnp.asarray(spec.act_t_dev, dtype=dt)[res_idx]  # (S,)
+            t_ready = t_ready + t_dev_s[:, None]
 
     # (4) fair uplink schedule (FairScheduler.order).  Cost is constant per
     # stream within a round, so the SFQ tag recurrence unrolls over slots
     # (per-stream arrivals strictly ascend, so slot order == t_ready order).
-    esc_flat = esc.reshape(-1)
-    t_ready_flat = jnp.where(esc, t_ready, inf).reshape(-1)
-    o = jnp.argsort(t_ready_flat)  # stable: ties keep (stream, slot) order
-    if spec.scheduler == "round_robin":
-        cost_s = payload_s / params.stream_bw / params.weights
-        tags = jnp.full((S, B), inf, dtype=dt)
-        prev = jnp.full((S,), _NEG, dtype=dt)
-        for d in range(B):
-            cand = jnp.maximum(t_ready[:, d], prev + cost_s)
-            tags = tags.at[:, d].set(jnp.where(esc[:, d], cand, inf))
-            prev = jnp.where(esc[:, d], cand, prev)
-        o = _lexsort2(tags.reshape(-1), o)
+    with jax.named_scope("round.schedule"):
+        esc_flat = esc.reshape(-1)
+        t_ready_flat = jnp.where(esc, t_ready, inf).reshape(-1)
+        o = jnp.argsort(t_ready_flat)  # stable: ties keep (stream, slot) order
+        if spec.scheduler == "round_robin":
+            cost_s = payload_s / params.stream_bw / params.weights
+            tags = jnp.full((S, B), inf, dtype=dt)
+            prev = jnp.full((S,), _NEG, dtype=dt)
+            for d in range(B):
+                cand = jnp.maximum(t_ready[:, d], prev + cost_s)
+                tags = tags.at[:, d].set(jnp.where(esc[:, d], cand, inf))
+                prev = jnp.where(esc[:, d], cand, prev)
+            o = _lexsort2(tags.reshape(-1), o)
 
     # (5) fabric transmit: per-cell masked Lindley over the scheduled rows
-    stream_flat = jnp.repeat(jnp.arange(S, dtype=jnp.int32), B)
-    s_o = stream_flat[o]
-    m_o = esc_flat[o]
-    sub_o = x.arr.reshape(-1)[o] + spec.t_fast  # real t_ready per row
-    if spec.has_splits:
-        sub_o = sub_o + t_dev_s[s_o]  # prefix runs before the upload
-    pay_o = params.sizes[res_idx[s_o]].astype(dt)
-    cell_o = params.cell_of[s_o]
-    end_tx = jnp.zeros((N,), dtype=dt)
-    cell_busy, cell_n = carry.cell_busy, carry.cell_n
-    cell_busy_s, cell_queued_s = carry.cell_busy_s, carry.cell_queued_s
-    fp_bad = carry.fp_bad
-    for c in range(C):
-        mk = m_o & (cell_o == c)
-        if spec.varying and (spec.cell_trace[c] or spec.cell_jitter[c] > 0):
-            key_c = None if carry.jit_key is None else carry.jit_key[c]
-            end_c, busy_c, wire_c, queued_c, bad_c = _masked_lindley_varying(
-                spec, params, c, key_c, sub_o, mk, pay_o, cell_busy[c])
-            fp_bad = fp_bad | bad_c
-        else:
-            end_c, busy_c, wire_c, queued_c = _masked_lindley(
-                sub_o, pay_o / params.cell_bw[c], mk, cell_busy[c])
-        end_tx = jnp.where(mk, end_c, end_tx)
-        cell_busy = cell_busy.at[c].set(busy_c)
-        cell_n = cell_n.at[c].add(mk.sum(dtype=jnp.int32))
-        cell_busy_s = cell_busy_s.at[c].add(wire_c)
-        cell_queued_s = cell_queued_s.at[c].add(queued_c)
+    with jax.named_scope("round.transmit"):
+        stream_flat = jnp.repeat(jnp.arange(S, dtype=jnp.int32), B)
+        s_o = stream_flat[o]
+        m_o = esc_flat[o]
+        sub_o = x.arr.reshape(-1)[o] + spec.t_fast  # real t_ready per row
+        if spec.has_splits:
+            sub_o = sub_o + t_dev_s[s_o]  # prefix runs before the upload
+        pay_o = params.sizes[res_idx[s_o]].astype(dt)
+        cell_o = params.cell_of[s_o]
+        end_tx = jnp.zeros((N,), dtype=dt)
+        cell_busy, cell_n = carry.cell_busy, carry.cell_n
+        cell_busy_s, cell_queued_s = carry.cell_busy_s, carry.cell_queued_s
+        fp_bad = carry.fp_bad
+        for c in range(C):
+            mk = m_o & (cell_o == c)
+            if spec.varying and (spec.cell_trace[c] or spec.cell_jitter[c] > 0):
+                key_c = None if carry.jit_key is None else carry.jit_key[c]
+                end_c, busy_c, wire_c, queued_c, bad_c = _masked_lindley_varying(
+                    spec, params, c, key_c, sub_o, mk, pay_o, cell_busy[c])
+                fp_bad = fp_bad | bad_c
+            else:
+                end_c, busy_c, wire_c, queued_c = _masked_lindley(
+                    sub_o, pay_o / params.cell_bw[c], mk, cell_busy[c])
+            end_tx = jnp.where(mk, end_c, end_tx)
+            cell_busy = cell_busy.at[c].set(busy_c)
+            cell_n = cell_n.at[c].add(mk.sum(dtype=jnp.int32))
+            cell_busy_s = cell_busy_s.at[c].add(wire_c)
+            cell_queued_s = cell_queued_s.at[c].add(queued_c)
 
     # (6) replica placement in upload-arrival order (Placement.assign)
-    end_m = jnp.where(m_o, end_tx, inf)
-    o2 = jnp.argsort(end_m)  # stable: ties keep scheduler order
-    m2 = m_o[o2]
-    rr_next = carry.rr_next
-    if spec.placement == "round_robin":
-        rank = jnp.cumsum(m2.astype(jnp.int32)) - 1
-        rep2 = (rr_next + rank) % K
-        rr_next = (rr_next + m_o.sum(dtype=jnp.int32)) % K
-    else:
-        st = params.replica_st.astype(dt)
+    with jax.named_scope("round.place"):
+        end_m = jnp.where(m_o, end_tx, inf)
+        o2 = jnp.argsort(end_m)  # stable: ties keep scheduler order
+        m2 = m_o[o2]
+        rr_next = carry.rr_next
+        if spec.placement == "round_robin":
+            rank = jnp.cumsum(m2.astype(jnp.int32)) - 1
+            rep2 = (rr_next + rank) % K
+            rr_next = (rr_next + m_o.sum(dtype=jnp.int32)) % K
+        else:
+            st = params.replica_st.astype(dt)
 
-        def pstep(busy, inp):
-            t_i, live = inp
-            if spec.placement == "jsq":
-                k = jnp.argmin(busy)
-            else:  # least_land
-                k = jnp.argmin(jnp.maximum(t_i, busy) + st)
-            upd = busy.at[k].set(jnp.maximum(t_i, busy[k]) + st[k])
-            return jnp.where(live, upd, busy), jnp.where(live, k, 0).astype(jnp.int32)
+            def pstep(busy, inp):
+                t_i, live = inp
+                if spec.placement == "jsq":
+                    k = jnp.argmin(busy)
+                else:  # least_land
+                    k = jnp.argmin(jnp.maximum(t_i, busy) + st)
+                upd = busy.at[k].set(jnp.maximum(t_i, busy[k]) + st[k])
+                return jnp.where(live, upd, busy), jnp.where(live, k, 0).astype(jnp.int32)
 
-        _, rep2 = jax.lax.scan(pstep, carry.rep_busy.astype(dt), (end_m[o2], m2))
-    replica_o = jnp.zeros((N,), jnp.int32).at[o2].set(rep2.astype(jnp.int32))
+            _, rep2 = jax.lax.scan(pstep, carry.rep_busy.astype(dt), (end_m[o2], m2))
+        replica_o = jnp.zeros((N,), jnp.int32).at[o2].set(rep2.astype(jnp.int32))
 
     # (7) replica pool service (ReplicaPool.process)
-    rep_busy, rep_n = carry.rep_busy, carry.rep_n
-    rep_busy_s, rep_queued_s = carry.rep_busy_s, carry.rep_queued_s
-    st_row = params.replica_st[replica_o].astype(dt)
-    if spec.has_splits:
-        # split suffixes cost srv_frac of the replica's service time
-        # (ReplicaPool.process's per-request service_scale); incompatible
-        # with continuous batching — jax_unsupported rejects that pairing
-        srv_o = jnp.asarray(spec.act_srv_frac, dtype=dt)[res_idx[s_o]]  # (N,)
-        st_row = st_row * srv_o
-    service_o = st_row  # per-row reported processing time (= whole-batch
-    # f(n) under continuous batching — ReplicaPool.last_service semantics)
-    avg_batch = carry.avg_batch
-    if spec.batch_kind != "none":
-        # continuous batching (ReplicaPool._process_batched): per replica,
-        # admission-window batch formation over arrival-sorted rows.  Each
-        # fori_loop iteration forms ONE batch via a rank-space pointer —
-        # O(N) iterations x O(N) work per replica, the same opt-in cost
-        # class as the per-row jsq/least_land scan above.
-        w = spec.batch_window
-        bcap = spec.batch_cap if spec.batch_cap > 0 else N
-        repk = jnp.where(m_o, replica_o, K)
-        o3 = _lexsort2(repk.astype(dt), jnp.argsort(jnp.where(m_o, end_tx, inf)))
-        m3 = m_o[o3]
-        a3, k3 = end_tx[o3], repk[o3]
-        done3 = jnp.zeros((N,), dtype=dt)
-        serv3 = jnp.zeros((N,), dtype=dt)
-        size3 = jnp.zeros((N,), dtype=dt)
-        for k in range(K):
-            mk = m3 & (k3 == k)
-            n_k = mk.sum(dtype=jnp.int32)
-            rk = jnp.cumsum(mk.astype(jnp.int32)) - 1  # rank within replica
+    with jax.named_scope("round.serve"):
+        rep_busy, rep_n = carry.rep_busy, carry.rep_n
+        rep_busy_s, rep_queued_s = carry.rep_busy_s, carry.rep_queued_s
+        st_row = params.replica_st[replica_o].astype(dt)
+        if spec.has_splits:
+            # split suffixes cost srv_frac of the replica's service time
+            # (ReplicaPool.process's per-request service_scale); incompatible
+            # with continuous batching — jax_unsupported rejects that pairing
+            srv_o = jnp.asarray(spec.act_srv_frac, dtype=dt)[res_idx[s_o]]  # (N,)
+            st_row = st_row * srv_o
+        service_o = st_row  # per-row reported processing time (= whole-batch
+        # f(n) under continuous batching — ReplicaPool.last_service semantics)
+        avg_batch = carry.avg_batch
+        if spec.batch_kind != "none":
+            # continuous batching (ReplicaPool._process_batched): per replica,
+            # admission-window batch formation over arrival-sorted rows.  Each
+            # fori_loop iteration forms ONE batch via a rank-space pointer —
+            # O(N) iterations x O(N) work per replica, the same opt-in cost
+            # class as the per-row jsq/least_land scan above.
+            w = spec.batch_window
+            bcap = spec.batch_cap if spec.batch_cap > 0 else N
+            repk = jnp.where(m_o, replica_o, K)
+            o3 = _lexsort2(repk.astype(dt), jnp.argsort(jnp.where(m_o, end_tx, inf)))
+            m3 = m_o[o3]
+            a3, k3 = end_tx[o3], repk[o3]
+            done3 = jnp.zeros((N,), dtype=dt)
+            serv3 = jnp.zeros((N,), dtype=dt)
+            size3 = jnp.zeros((N,), dtype=dt)
+            for k in range(K):
+                mk = m3 & (k3 == k)
+                n_k = mk.sum(dtype=jnp.int32)
+                rk = jnp.cumsum(mk.astype(jnp.int32)) - 1  # rank within replica
 
-            def bstep(i, st7, mk=mk, rk=rk, n_k=n_k):
-                p, busy, done_k, serv_k, size_k, wire_k, queued_k = st7
-                live = p < n_k
-                rem = mk & (rk >= p)  # not-yet-batched rows, a3 ascending
-                a0 = jnp.min(jnp.where(rem, a3, inf))
-                t_open = jnp.maximum(busy, a0)
-                nwin = (rem & (a3 <= t_open + w)).sum(dtype=jnp.int32)
-                count = jnp.minimum(nwin, bcap)
-                member = rem & (rk < p + count)  # smallest-a3 rows first
-                arr_last = jnp.max(jnp.where(member, a3, _NEG))
-                # cap binding: launch at the last member's landing; else
-                # when the admission window closes
-                t_start = jnp.where(nwin > bcap,
-                                    jnp.maximum(t_open, arr_last), t_open + w)
-                fb = _batch_latency(spec, count.astype(dt))
-                done_v = t_start + fb
-                upd = member & live
-                done_k = jnp.where(upd, done_v, done_k)
-                serv_k = jnp.where(upd, fb, serv_k)
-                size_k = jnp.where(upd, count.astype(dt), size_k)
-                wire_k = wire_k + jnp.where(live, fb, 0.0)
-                queued_k = queued_k + jnp.where(upd, t_start - a3, 0.0).sum()
-                busy = jnp.where(live, done_v, busy)
-                p = p + jnp.where(live, count, 0)
-                return p, busy, done_k, serv_k, size_k, wire_k, queued_k
+                def bstep(i, st7, mk=mk, rk=rk, n_k=n_k):
+                    p, busy, done_k, serv_k, size_k, wire_k, queued_k = st7
+                    live = p < n_k
+                    rem = mk & (rk >= p)  # not-yet-batched rows, a3 ascending
+                    a0 = jnp.min(jnp.where(rem, a3, inf))
+                    t_open = jnp.maximum(busy, a0)
+                    nwin = (rem & (a3 <= t_open + w)).sum(dtype=jnp.int32)
+                    count = jnp.minimum(nwin, bcap)
+                    member = rem & (rk < p + count)  # smallest-a3 rows first
+                    arr_last = jnp.max(jnp.where(member, a3, _NEG))
+                    # cap binding: launch at the last member's landing; else
+                    # when the admission window closes
+                    t_start = jnp.where(nwin > bcap,
+                                        jnp.maximum(t_open, arr_last), t_open + w)
+                    fb = _batch_latency(spec, count.astype(dt))
+                    done_v = t_start + fb
+                    upd = member & live
+                    done_k = jnp.where(upd, done_v, done_k)
+                    serv_k = jnp.where(upd, fb, serv_k)
+                    size_k = jnp.where(upd, count.astype(dt), size_k)
+                    wire_k = wire_k + jnp.where(live, fb, 0.0)
+                    queued_k = queued_k + jnp.where(upd, t_start - a3, 0.0).sum()
+                    busy = jnp.where(live, done_v, busy)
+                    p = p + jnp.where(live, count, 0)
+                    return p, busy, done_k, serv_k, size_k, wire_k, queued_k
 
-            init = (jnp.zeros((), jnp.int32), rep_busy[k].astype(dt),
-                    done3, serv3, size3, jnp.zeros((), dt), jnp.zeros((), dt))
-            (_, busy_k, done3, serv3, size3, wire_k,
-             queued_k) = jax.lax.fori_loop(0, N, bstep, init)
-            rep_busy = rep_busy.at[k].set(busy_k)
-            rep_n = rep_n.at[k].add(n_k)
-            rep_busy_s = rep_busy_s.at[k].add(wire_k)
-            rep_queued_s = rep_queued_s.at[k].add(queued_k)
-        done_o = jnp.zeros((N,), dtype=dt).at[o3].set(done3)
-        service_o = jnp.zeros((N,), dtype=dt).at[o3].set(serv3)
-        size_o = jnp.zeros((N,), dtype=dt).at[o3].set(size3)
-        n_live = m_o.sum(dtype=jnp.int32)
-        obs = jnp.where(m_o, size_o, 0.0).sum() / jnp.maximum(n_live, 1)
-        avg_batch = jnp.where(
-            n_live > 0,
-            (1.0 - spec.batch_beta) * carry.avg_batch + spec.batch_beta * obs,
-            carry.avg_batch)
-    elif spec.serial_replicas:
-        repk = jnp.where(m_o, replica_o, K)
-        o3 = _lexsort2(repk.astype(dt), jnp.argsort(jnp.where(m_o, end_tx, inf)))
-        m3 = m_o[o3]
-        a3, k3 = end_tx[o3], repk[o3]
-        done3 = jnp.zeros((N,), dtype=dt)
-        for k in range(K):
-            mk = m3 & (k3 == k)
-            st_k = (params.replica_st[k].astype(dt) * srv_o[o3]
-                    if spec.has_splits
-                    else jnp.full((N,), params.replica_st[k], dtype=dt))
-            end_k, busy_k, wire_k, queued_k = _masked_lindley(
-                a3, st_k, mk, rep_busy[k])
-            done3 = jnp.where(mk, end_k, done3)
-            rep_busy = rep_busy.at[k].set(busy_k)
-            rep_n = rep_n.at[k].add(mk.sum(dtype=jnp.int32))
-            rep_busy_s = rep_busy_s.at[k].add(wire_k)
-            rep_queued_s = rep_queued_s.at[k].add(queued_k)
-        done_o = jnp.zeros((N,), dtype=dt).at[o3].set(done3)
-    else:  # infinite-capacity fixed delay (paper semantics)
-        done_o = end_tx + st_row
-        for k in range(K):
-            mk = m_o & (replica_o == k)
-            rep_n = rep_n.at[k].add(mk.sum(dtype=jnp.int32))
-            rep_busy_s = rep_busy_s.at[k].add(
-                jnp.where(mk, st_row, 0.0).sum())
-            rep_busy = rep_busy.at[k].set(jnp.maximum(
-                rep_busy[k], jnp.where(mk, done_o, _NEG).max()))
-    lands_o = done_o + spec.latency
+                init = (jnp.zeros((), jnp.int32), rep_busy[k].astype(dt),
+                        done3, serv3, size3, jnp.zeros((), dt), jnp.zeros((), dt))
+                (_, busy_k, done3, serv3, size3, wire_k,
+                 queued_k) = jax.lax.fori_loop(0, N, bstep, init)
+                rep_busy = rep_busy.at[k].set(busy_k)
+                rep_n = rep_n.at[k].add(n_k)
+                rep_busy_s = rep_busy_s.at[k].add(wire_k)
+                rep_queued_s = rep_queued_s.at[k].add(queued_k)
+            done_o = jnp.zeros((N,), dtype=dt).at[o3].set(done3)
+            service_o = jnp.zeros((N,), dtype=dt).at[o3].set(serv3)
+            size_o = jnp.zeros((N,), dtype=dt).at[o3].set(size3)
+            n_live = m_o.sum(dtype=jnp.int32)
+            obs = jnp.where(m_o, size_o, 0.0).sum() / jnp.maximum(n_live, 1)
+            avg_batch = jnp.where(
+                n_live > 0,
+                (1.0 - spec.batch_beta) * carry.avg_batch + spec.batch_beta * obs,
+                carry.avg_batch)
+        elif spec.serial_replicas:
+            repk = jnp.where(m_o, replica_o, K)
+            o3 = _lexsort2(repk.astype(dt), jnp.argsort(jnp.where(m_o, end_tx, inf)))
+            m3 = m_o[o3]
+            a3, k3 = end_tx[o3], repk[o3]
+            done3 = jnp.zeros((N,), dtype=dt)
+            for k in range(K):
+                mk = m3 & (k3 == k)
+                st_k = (params.replica_st[k].astype(dt) * srv_o[o3]
+                        if spec.has_splits
+                        else jnp.full((N,), params.replica_st[k], dtype=dt))
+                end_k, busy_k, wire_k, queued_k = _masked_lindley(
+                    a3, st_k, mk, rep_busy[k])
+                done3 = jnp.where(mk, end_k, done3)
+                rep_busy = rep_busy.at[k].set(busy_k)
+                rep_n = rep_n.at[k].add(mk.sum(dtype=jnp.int32))
+                rep_busy_s = rep_busy_s.at[k].add(wire_k)
+                rep_queued_s = rep_queued_s.at[k].add(queued_k)
+            done_o = jnp.zeros((N,), dtype=dt).at[o3].set(done3)
+        else:  # infinite-capacity fixed delay (paper semantics)
+            done_o = end_tx + st_row
+            for k in range(K):
+                mk = m_o & (replica_o == k)
+                rep_n = rep_n.at[k].add(mk.sum(dtype=jnp.int32))
+                rep_busy_s = rep_busy_s.at[k].add(
+                    jnp.where(mk, st_row, 0.0).sum())
+                rep_busy = rep_busy.at[k].set(jnp.maximum(
+                    rep_busy[k], jnp.where(mk, done_o, _NEG).max()))
+        lands_o = done_o + spec.latency
 
     # (8) deadline check + final correctness
-    arr_o = x.arr.reshape(-1)[o].astype(dt)
-    ok_o = m_o & (lands_o <= arr_o + spec.deadline)
-    lands_grid = jnp.zeros((N,), dtype=dt).at[o].set(lands_o).reshape(S, B)
-    ok_grid = jnp.zeros((N,), bool).at[o].set(ok_o).reshape(S, B)
-    eval_res = (jnp.asarray(spec.act_res, jnp.int32)[res_idx]
-                if spec.has_splits else res_idx)  # action -> eval resolution
-    slow_sel = jnp.take_along_axis(
-        x.slow_ok, eval_res[:, None, None].astype(jnp.int32), axis=2)[..., 0]
-    final_ok = jnp.where(ok_grid, slow_sel, x.fast_ok)
-    correct_r = (final_ok & valid).sum(axis=1, dtype=jnp.int32)
+    with jax.named_scope("round.deadline"):
+        arr_o = x.arr.reshape(-1)[o].astype(dt)
+        ok_o = m_o & (lands_o <= arr_o + spec.deadline)
+        lands_grid = jnp.zeros((N,), dtype=dt).at[o].set(lands_o).reshape(S, B)
+        ok_grid = jnp.zeros((N,), bool).at[o].set(ok_o).reshape(S, B)
+        eval_res = (jnp.asarray(spec.act_res, jnp.int32)[res_idx]
+                    if spec.has_splits else res_idx)  # action -> eval resolution
+        slow_sel = jnp.take_along_axis(
+            x.slow_ok, eval_res[:, None, None].astype(jnp.int32), axis=2)[..., 0]
+        final_ok = jnp.where(ok_grid, slow_sel, x.fast_ok)
+        correct_r = (final_ok & valid).sum(axis=1, dtype=jnp.int32)
 
     # (9) EWMA bandwidth observations in transmission order
     # (FleetRunner.observe_bandwidth; replica queueing deliberately included;
     # replies report their actual processing time — the whole-batch f(n)
     # under continuous batching, per-request service time otherwise)
-    seconds_o = lands_o - sub_o - spec.latency - service_o
-    okbw = m_o & (seconds_o > 1e-9)
-    rate_o = pay_o / jnp.where(okbw, seconds_o, 1.0)
-    bw_est = ewma_fold(carry.bw_est, spec.bw_alpha, s_o, rate_o, okbw, S, B)
-    bw_est = shard(bw_est, "streams")
+    with jax.named_scope("round.observe"):
+        seconds_o = lands_o - sub_o - spec.latency - service_o
+        okbw = m_o & (seconds_o > 1e-9)
+        rate_o = pay_o / jnp.where(okbw, seconds_o, 1.0)
+        bw_est = ewma_fold(carry.bw_est, spec.bw_alpha, s_o, rate_o, okbw, S, B)
+        bw_est = shard(bw_est, "streams")
 
     # (10) backlog bookkeeping: consume planned offloads, extend the rest
-    add = valid & ~esc
-    if spec.groups:
-        # mixed per-policy semantics: one consume pass takes the non-
-        # oneshot offloads and clears the oneshot streams (FleetRunner
-        # .consume), then extend trims each stream to its group's bound
-        osh = jnp.asarray(g_oneshot)
-        fleet = consume_fleet(fleet, (dec >= 0) & ~osh[:, None], osh & active)
-        fleet = extend_fleet(fleet, arr, conf, add, jnp.asarray(g_mb))
-    else:
-        if spec.oneshot:
-            fleet = clear_fleet(fleet, active)
+    with jax.named_scope("round.backlog"):
+        add = valid & ~esc
+        if spec.groups:
+            # mixed per-policy semantics: one consume pass takes the non-
+            # oneshot offloads and clears the oneshot streams (FleetRunner
+            # .consume), then extend trims each stream to its group's bound
+            osh = jnp.asarray(g_oneshot)
+            fleet = consume_fleet(fleet, (dec >= 0) & ~osh[:, None], osh & active)
+            fleet = extend_fleet(fleet, arr, conf, add, jnp.asarray(g_mb))
         else:
-            fleet = consume_fleet(fleet, dec >= 0, jnp.zeros((S,), bool))
-        fleet = extend_fleet(fleet, arr, conf, add, spec.planner.L)
+            if spec.oneshot:
+                fleet = clear_fleet(fleet, active)
+            else:
+                fleet = consume_fleet(fleet, dec >= 0, jnp.zeros((S,), bool))
+            fleet = extend_fleet(fleet, arr, conf, add, spec.planner.L)
 
     # (11) metrics (AggregateMetrics.update_round inputs)
-    lat = jnp.full((S, B), spec.t_fast, dtype=dt)
-    lat = jnp.where(ok_grid, lands_grid - arr, lat)
-    miss_grid = esc & ~ok_grid
-    lat = jnp.where(miss_grid, spec.deadline, lat)
-    off_counts = ok_grid.sum(axis=1, dtype=jnp.int32)
-    miss_counts = miss_grid.sum(axis=1, dtype=jnp.int32)
+    with jax.named_scope("round.metrics"):
+        lat = jnp.full((S, B), spec.t_fast, dtype=dt)
+        lat = jnp.where(ok_grid, lands_grid - arr, lat)
+        miss_grid = esc & ~ok_grid
+        lat = jnp.where(miss_grid, spec.deadline, lat)
+        off_counts = ok_grid.sum(axis=1, dtype=jnp.int32)
+        miss_counts = miss_grid.sum(axis=1, dtype=jnp.int32)
 
-    out = EngineCarry(
-        fleet=fleet, bw_est=bw_est,
-        cell_busy=cell_busy, cell_n=cell_n, cell_busy_s=cell_busy_s,
-        cell_queued_s=cell_queued_s,
-        rep_busy=rep_busy, rep_n=rep_n, rep_busy_s=rep_busy_s,
-        rep_queued_s=rep_queued_s, rr_next=rr_next,
-        frames=carry.frames + valid.sum(axis=1, dtype=jnp.int32),
-        offloaded=carry.offloaded + off_counts,
-        missed=carry.missed + miss_counts,
-        correct=carry.correct + correct_r,
-        avg_batch=avg_batch, jit_key=carry.jit_key, fp_bad=fp_bad)
+        out = EngineCarry(
+            fleet=fleet, bw_est=bw_est,
+            cell_busy=cell_busy, cell_n=cell_n, cell_busy_s=cell_busy_s,
+            cell_queued_s=cell_queued_s,
+            rep_busy=rep_busy, rep_n=rep_n, rep_busy_s=rep_busy_s,
+            rep_queued_s=rep_queued_s, rr_next=rr_next,
+            frames=carry.frames + valid.sum(axis=1, dtype=jnp.int32),
+            offloaded=carry.offloaded + off_counts,
+            missed=carry.missed + miss_counts,
+            correct=carry.correct + correct_r,
+            avg_batch=avg_batch, jit_key=carry.jit_key, fp_bad=fp_bad)
 
-    if spec.collect == "none":
+        if spec.collect == "none":
+            if spec.telemetry:
+                raise ValueError("spec.telemetry needs collect >= 'metrics' — "
+                                 "the recorder's series ride on the ys pytree")
+            return out, None
+        z0 = jnp.zeros((0,))
+        extras = dict(theta=z0, res_idx=z0, cap=z0, n_off=z0, n_frames=z0,
+                      dec=z0, esc=z0, ok=z0, bw_est=z0, lengths=z0,
+                      overflow=z0, inexact=z0)
+        if spec.collect == "trace":
+            extras = dict(theta=theta, res_idx=res_idx, cap=cap, n_off=n_off,
+                          n_frames=plan.n_frames, dec=dec, esc=esc, ok=ok_grid,
+                          bw_est=bw_est, lengths=fleet.length,
+                          overflow=plan.overflow, inexact=plan.inexact)
         if spec.telemetry:
-            raise ValueError("spec.telemetry needs collect >= 'metrics' — "
-                             "the recorder's series ride on the ys pytree")
-        return out, None
-    z0 = jnp.zeros((0,))
-    extras = dict(theta=z0, res_idx=z0, cap=z0, n_off=z0, n_frames=z0,
-                  dec=z0, esc=z0, ok=z0, bw_est=z0, lengths=z0,
-                  overflow=z0, inexact=z0)
-    if spec.collect == "trace":
-        extras = dict(theta=theta, res_idx=res_idx, cap=cap, n_off=n_off,
-                      n_frames=plan.n_frames, dec=dec, esc=esc, ok=ok_grid,
-                      bw_est=bw_est, lengths=fleet.length,
-                      overflow=plan.overflow, inexact=plan.inexact)
-    if spec.telemetry:
-        # the FleetRecorder's per-round record (obs/timeseries.py): the
-        # cumulative per-stream counters come from host cumsums of the
-        # off/miss/correct columns above (integer-exact), so only the
-        # simulated-state series are emitted here.  The histogram over the
-        # action table is exact: every planned offload of stream s carries
-        # action res_idx[s], and inactive/pad rows plan n_off == 0.
-        A = params.sizes.shape[0]
-        extras.update(
-            ts_bw_est=bw_est,
-            ts_off_hist=jnp.zeros((A,), jnp.int32).at[res_idx].add(
-                n_off.astype(jnp.int32)),
-            ts_cell_busy_s=cell_busy_s, ts_cell_queued_s=cell_queued_s,
-            ts_rep_busy_s=rep_busy_s, ts_rep_queued_s=rep_queued_s,
-            ts_avg_batch=avg_batch,
-            ts_st_est=(st_eff if st_eff is not None
-                       else jnp.asarray(spec.planner.server_time, dtype=dt)))
-    ys = RoundTrace(off_counts=off_counts, miss_counts=miss_counts,
-                    correct=correct_r, lat=lat, **extras)
-    return out, ys
+            # the FleetRecorder's per-round record (obs/timeseries.py): the
+            # cumulative per-stream counters come from host cumsums of the
+            # off/miss/correct columns above (integer-exact), so only the
+            # simulated-state series are emitted here.  The histogram over the
+            # action table is exact: every planned offload of stream s carries
+            # action res_idx[s], and inactive/pad rows plan n_off == 0.
+            A = params.sizes.shape[0]
+            extras.update(
+                ts_bw_est=bw_est,
+                ts_off_hist=jnp.zeros((A,), jnp.int32).at[res_idx].add(
+                    n_off.astype(jnp.int32)),
+                ts_cell_busy_s=cell_busy_s, ts_cell_queued_s=cell_queued_s,
+                ts_rep_busy_s=rep_busy_s, ts_rep_queued_s=rep_queued_s,
+                ts_avg_batch=avg_batch,
+                ts_st_est=(st_eff if st_eff is not None
+                           else jnp.asarray(spec.planner.server_time, dtype=dt)))
+        ys = RoundTrace(off_counts=off_counts, miss_counts=miss_counts,
+                        correct=correct_r, lat=lat, **extras)
+        return out, ys
 
 
 def make_engine(spec: EngineSpec):

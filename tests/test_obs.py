@@ -210,8 +210,91 @@ def test_profiler_unit():
     s = p.summarize()
     assert s["x"] == {"total_s": 1.0, "calls": 2, "mean_ms": 500.0}
     assert s["y"]["calls"] == 1
+    assert "counters" not in s
+    p.count("slow_frames", 3)
+    p.count("slow_frames", np.int64(4))
+    assert p.counters == {"slow_frames": 7}
+    assert p.summarize()["counters"] == {"slow_frames": 7}
     p.reset()
-    assert not p
+    assert not p and p.counters == {}
+    p.count("h2d_bytes", 0)
+    assert p  # a counter alone is a sample
+
+
+# the spans each backend opens under Telemetry(profile=True), as
+# docs/observability.md lists them ("record" only with the recorder on)
+SPANS = {
+    "numpy": {"plan", "serve", "transmit", "fold"},
+    "jax": {"prepare", "precompute", "upload", "tier_fast", "tier_slow",
+            "host_read", "pad", "scan", "fold", "record"},
+}
+
+
+def _host_spans(trace_dir):
+    """(name, start, end) of every host event of a profiler trace."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)[0]
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_profiler_spans_land_on_the_trace_nested_in_the_caller(backend, tmp_path):
+    """Every documented ``repro.*`` span is on the profiler trace's host
+    plane, inside the caller's own annotation, and its seconds are in
+    ``totals``."""
+    import jax
+
+    tel = Telemetry(record=True, profile=True)
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("caller.segment"):
+            _run(backend, telemetry=tel)
+    spans = _host_spans(tmp_path)
+    (_, lo, hi), = [x for x in spans if x[0] == "caller.segment"]
+    ours = [x for x in spans if x[0].startswith("repro.")]
+    assert {n[len("repro."):] for n, _, _ in ours} == SPANS[backend]
+    assert all(lo <= s and e <= hi for _, s, e in ours)
+    assert set(tel.profiler.totals) == SPANS[backend]
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_no_span_and_no_clock_without_telemetry(backend, monkeypatch):
+    """``telemetry=None`` constructs no ``TraceAnnotation`` and never
+    reaches the profiler's clock or counters."""
+    import jax
+
+    from repro.serving import engine
+
+    def refuse(*a, **k):
+        raise AssertionError("touched with telemetry off")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    for name in ("phase", "add", "count"):
+        monkeypatch.setattr(PhaseProfiler, name, refuse)
+    assert not hasattr(engine, "time")  # the engines read no clock of their own
+    m, _ = _run(backend)
+    assert m.n_frames > 0
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_slow_frames_and_h2d_bytes_counters(backend):
+    """The jax bridge sends every frame through the slow tier at every
+    rung (S·N·m); the numpy engine sends only the escalated frames."""
+    S, n = 6, 48
+    imgs, _ = synthetic_streams(S, n, seed=0)
+    tel = Telemetry(record=False, profile=True)
+    m, srv = _run(backend, S=S, n=n, telemetry=tel)
+    m_rungs = len(srv.cfg.resolutions)
+    escalated = int(m.n_offloaded + m.n_deadline_miss)
+    assert 0 < escalated < S * n
+    want = S * n * m_rungs if backend == "jax" else escalated
+    assert tel.profiler.counters["slow_frames"] == want
+    assert tel.profiler.counters["h2d_bytes"] == imgs.nbytes
 
 
 def test_aot_split_times_compile():
