@@ -14,7 +14,8 @@ can compile:
     ``clear``) become per-stream mask-and-compact passes, vmapped over the
     fleet — compaction is one stable ``argsort(~keep)``, which moves kept
     slots to the front without reordering them;
-  * the planners become per-stream fixed-shape functions, vmapped: the
+  * the planners become per-stream fixed-shape functions, vmapped, whose
+    backlog walk stops at the fleet's deepest live backlog: the
     CBO frontier DP runs with a capped frontier of ``F`` states and
     reports an ``overflow`` flag when the cap would have truncated it
     (the differential tests assert the flag stays clean), plus an
@@ -246,6 +247,7 @@ class PlanOut(NamedTuple):
     n_frames: jnp.ndarray  # (S,) int32
     overflow: jnp.ndarray  # (S,) bool — frontier cap would have truncated
     inexact: jnp.ndarray  # (S,) bool — eps-window prune disagreement possible
+    depth: jnp.ndarray  # () int32 — backlog depths walked (deepest live backlog)
 
 
 def _summarize(dec, conf, length, gain, spec: PlannerSpec):
@@ -266,12 +268,14 @@ def _summarize(dec, conf, length, gain, spec: PlannerSpec):
     return theta, r0, n_off, gain, base
 
 
-def _plan_local_single(arr, conf, length, now, bw, st, spec: PlannerSpec):
+def _plan_local_single(arr, conf, length, now, bw, st, depth,
+                       spec: PlannerSpec):
     dec = jnp.full((spec.L,), -1, dtype=jnp.int8)
     return dec, jnp.asarray(0.0, dtype=arr.dtype), jnp.asarray(False), jnp.asarray(False)
 
 
-def _plan_server_single(arr, conf, length, now, bw, st, spec: PlannerSpec):
+def _plan_server_single(arr, conf, length, now, bw, st, depth,
+                        spec: PlannerSpec):
     """ServerPolicy.plan_many: highest resolution sustainable within both
     the frame interval and the deadline budget; offload every frame."""
     L, m = spec.L, spec.m
@@ -292,7 +296,8 @@ def _plan_server_single(arr, conf, length, now, bw, st, spec: PlannerSpec):
     return dec, gain, jnp.asarray(False), jnp.asarray(False)
 
 
-def _plan_threshold_single(arr, conf, length, now, bw, st, spec: PlannerSpec):
+def _plan_threshold_single(arr, conf, length, now, bw, st, depth,
+                           spec: PlannerSpec):
     """ThresholdPolicy.plan_many: serial acceptance in backlog order at a
     fixed resolution — same max-plus accumulation, same order."""
     L, m = spec.L, spec.m
@@ -314,17 +319,21 @@ def _plan_threshold_single(arr, conf, length, now, bw, st, spec: PlannerSpec):
 
     t0 = now.astype(arr.dtype)
     _, gain, dec = jax.lax.fori_loop(
-        0, L, body, (t0, jnp.asarray(0.0, dtype=arr.dtype),
-                     jnp.full((L,), -1, dtype=jnp.int8)))
+        0, depth, body, (t0, jnp.asarray(0.0, dtype=arr.dtype),
+                         jnp.full((L,), -1, dtype=jnp.int8)))
     return dec, gain, jnp.asarray(False), jnp.asarray(False)
 
 
-def _plan_cbo_single(arr, conf, length, now, bw, st, spec: PlannerSpec):
+def _plan_cbo_single(arr, conf, length, now, bw, st, depth,
+                     spec: PlannerSpec):
     """``cbo_plan`` (paper Algorithm 1) with a capped fixed-shape frontier.
 
     Semantics notes vs ``frontier.py``:
       * frames walk in confidence-descending stable order; invalid slots
-        sort last (conf key -inf) so depths >= length are pure carries;
+        sort last (conf key -inf) so depths >= length are pure carries:
+        no expansion is feasible and the sort reproduces the frontier, so
+        the walk stops at ``depth``, the deepest backlog of the planned
+        streams, with every output as the full ``L`` walk gives it;
       * candidates are [frontier carries] ++ [expansions, state-major /
         resolution-minor] — infeasible rows are masked (t=+inf, gain=-inf)
         instead of removed, which the stable (t, -gain, idx) sort sends to
@@ -344,11 +353,12 @@ def _plan_cbo_single(arr, conf, length, now, bw, st, spec: PlannerSpec):
 
     Split action tables dispatch to ``_plan_cbo_actions`` (the same DP over
     the enlarged {frame@res} ∪ {features@cut} grid); the frame-only body
-    below stays byte-identical so its compiled graph — and the snapshot
-    goldens pinned to it — never changes.
+    below stays as it was, so the snapshot goldens pinned to it never
+    change, except that its loop bound is the fleet's deepest backlog
+    (``depth``, one unbatched scalar), not the pad ``L``.
     """
     if spec.split_sizes:
-        return _plan_cbo_actions(arr, conf, length, now, bw, st, spec)
+        return _plan_cbo_actions(arr, conf, length, now, bw, st, depth, spec)
     L, m, F = spec.L, spec.m, spec.frontier
     dt = arr.dtype
     rtt = st + spec.latency
@@ -411,14 +421,15 @@ def _plan_cbo_single(arr, conf, length, now, bw, st, spec: PlannerSpec):
     f_valid = jnp.zeros((F,), dtype=bool).at[0].set(True)
     f_dec = jnp.full((F, L), -1, dtype=jnp.int8)
     f_t, f_gain, f_valid, f_dec, overflow, inexact = jax.lax.fori_loop(
-        0, L, body, (f_t, f_gain, f_valid, f_dec,
-                     jnp.asarray(False), jnp.asarray(False)))
+        0, depth, body, (f_t, f_gain, f_valid, f_dec,
+                         jnp.asarray(False), jnp.asarray(False)))
     best = jnp.argmax(jnp.where(f_valid, f_gain, neg))  # first max, np.argmax order
     gain = jnp.where(f_valid[best], f_gain[best], 0.0)
     return f_dec[best], gain, overflow, inexact
 
 
-def _plan_cbo_actions(arr, conf, length, now, bw, st, spec: PlannerSpec):
+def _plan_cbo_actions(arr, conf, length, now, bw, st, depth,
+                      spec: PlannerSpec):
     """``cbo_plan`` over the full action grid — ``_plan_cbo_single`` with
     per-action columns instead of per-resolution ones (the jnp mirror of
     ``frontier._action_vectors``):
@@ -496,14 +507,15 @@ def _plan_cbo_actions(arr, conf, length, now, bw, st, spec: PlannerSpec):
     f_valid = jnp.zeros((F,), dtype=bool).at[0].set(True)
     f_dec = jnp.full((F, L), -1, dtype=jnp.int8)
     f_t, f_gain, f_valid, f_dec, overflow, inexact = jax.lax.fori_loop(
-        0, L, body, (f_t, f_gain, f_valid, f_dec,
-                     jnp.asarray(False), jnp.asarray(False)))
+        0, depth, body, (f_t, f_gain, f_valid, f_dec,
+                         jnp.asarray(False), jnp.asarray(False)))
     best = jnp.argmax(jnp.where(f_valid, f_gain, neg))
     gain = jnp.where(f_valid[best], f_gain[best], 0.0)
     return f_dec[best], gain, overflow, inexact
 
 
-def _plan_greedy_rate_single(arr, conf, length, now, bw, st, spec: PlannerSpec):
+def _plan_greedy_rate_single(arr, conf, length, now, bw, st, depth,
+                             spec: PlannerSpec):
     """GreedyRatePolicy._plan: per frame in backlog order, walk resolutions
     from the highest down, stop at the first whose server accuracy no longer
     beats the local tier, offload at the first that also meets the deadline;
@@ -541,8 +553,8 @@ def _plan_greedy_rate_single(arr, conf, length, now, bw, st, spec: PlannerSpec):
         return t, gain, dec
 
     _, gain, dec = jax.lax.fori_loop(
-        0, L, body, (now.astype(dt), jnp.asarray(0.0, dtype=dt),
-                     jnp.full((L,), -1, dtype=jnp.int8)))
+        0, depth, body, (now.astype(dt), jnp.asarray(0.0, dtype=dt),
+                         jnp.full((L,), -1, dtype=jnp.int8)))
     return dec, gain, jnp.asarray(False), jnp.asarray(False)
 
 
@@ -564,14 +576,27 @@ def plan_fleet(spec: PlannerSpec, fleet: PaddedFleet, now, bw,
     ``server_time`` overrides the spec's static nominal T^o with a traced
     scalar (the occupancy-calibrated estimate under a batching slow tier);
     ``None`` keeps the original static-constant compiled graph.
+
+    The backlog walk takes ``fleet.length.max()`` steps, not ``L``: depths
+    past a stream's length change none of its outputs.  The bound is one
+    unbatched scalar, so the vmapped loop is a plain ``while`` with no
+    per-stream predicate.
     """
+    return _plan_to_depth(spec, fleet, now, bw, server_time,
+                          fleet.length.max(initial=0))
+
+
+def _plan_to_depth(spec: PlannerSpec, fleet: PaddedFleet, now, bw,
+                   server_time, depth) -> PlanOut:
+    """``plan_fleet`` with the backlog walk bounded by ``depth``."""
     single = _PLANNERS[spec.kind]
     st = spec.server_time if server_time is None \
         else jnp.asarray(server_time, dtype=spec.dtype)
+    depth = jnp.asarray(depth, dtype=jnp.int32)
 
     def one(arr, conf, length, now_s, bw_s):
         dec, gain, overflow, inexact = single(arr, conf, length, now_s, bw_s,
-                                              st, spec)
+                                              st, depth, spec)
         theta, r0, n_off, gain, base = _summarize(dec, conf, length, gain, spec)
         return dec, theta, r0, n_off, gain, base, overflow, inexact
 
@@ -579,7 +604,8 @@ def plan_fleet(spec: PlannerSpec, fleet: PaddedFleet, now, bw,
         fleet.arrival, fleet.conf, fleet.length, now, bw)
     return PlanOut(dec=dec, theta=theta, resolution=r0, n_offloads=n_off,
                    total_gain=gain, base_acc=base,
-                   n_frames=fleet.length, overflow=ovf, inexact=inx)
+                   n_frames=fleet.length, overflow=ovf, inexact=inx,
+                   depth=depth)
 
 
 def make_planner(spec: PlannerSpec):

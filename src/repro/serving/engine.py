@@ -486,7 +486,10 @@ class MultiStreamServer:
         ``tier_slow`` per rung, ``host_read`` around each device-to-host
         read, ``pad``), ``scan``, ``fold`` and ``record``; the counters
         ``slow_frames`` and ``h2d_bytes`` count the slow tier's forwards
-        and the frame bytes sent to the device."""
+        and the frame bytes sent to the device, ``plan_steps`` the backlog
+        depths the compiled planners walked and ``plan_steps_padded`` the
+        depths a walk over the whole pad would take (``L`` a round and
+        planner)."""
         import jax.numpy as jnp
 
         from repro.serving import engine_jax as ej
@@ -594,6 +597,10 @@ class MultiStreamServer:
             miss = np.asarray(ys.miss_counts)[:, :S]
             corr = np.asarray(ys.correct)[:, :S]
             lat = np.asarray(ys.lat, dtype=np.float64)[:, :S]
+            if prof is not None:
+                prof.count("plan_steps", np.asarray(ys.plan_depth).sum())
+                prof.count("plan_steps_padded", len(per_round) * spec.planner.L
+                           * max(1, len(spec.groups)))
             for i, (start, b) in enumerate(per_round):
                 valid_i = rounds[i][1][:S, :b]
                 self.metrics.update_round(valid_i.sum(axis=1), off[i], miss[i],

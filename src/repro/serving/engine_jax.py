@@ -201,6 +201,7 @@ class RoundTrace(NamedTuple):
     miss_counts: jnp.ndarray  # (S,) int32
     correct: jnp.ndarray  # (S,) int32
     lat: jnp.ndarray  # (S, B)
+    plan_depth: jnp.ndarray  # () int32 — backlog depths the planners walked
     # -- collect == "trace" extras (zero-size placeholders otherwise) ----- #
     theta: jnp.ndarray
     res_idx: jnp.ndarray
@@ -365,7 +366,8 @@ def _plan_groups(spec: EngineSpec, fleet: PaddedFleet, now, bw, st_eff):
     compiling one planner subgraph per group.  Stream order inside the
     engine is never permuted (the SFQ/argsort tie-breaks key on global
     stream ids); streams outside every group (S-padding) keep the
-    inactive-row defaults (dec=-1, theta=0, r°=m-1)."""
+    inactive-row defaults (dec=-1, theta=0, r°=m-1).  Each group walks to
+    its own deepest backlog; ``depth`` is the sum of the groups' walks."""
     S, L, m = spec.n_streams, spec.planner.L, spec.m
     dt = spec.planner.dtype
     out = PlanOut(
@@ -377,7 +379,8 @@ def _plan_groups(spec: EngineSpec, fleet: PaddedFleet, now, bw, st_eff):
         base_acc=jnp.zeros((S,), dtype=dt),
         n_frames=fleet.length,
         overflow=jnp.zeros((S,), bool),
-        inexact=jnp.zeros((S,), bool))
+        inexact=jnp.zeros((S,), bool),
+        depth=jnp.zeros((), jnp.int32))
     for g in spec.groups:
         idx = jnp.asarray(g.streams, dtype=jnp.int32)
         sub = PaddedFleet(fleet.arrival[idx], fleet.conf[idx], fleet.length[idx])
@@ -391,7 +394,8 @@ def _plan_groups(spec: EngineSpec, fleet: PaddedFleet, now, bw, st_eff):
             base_acc=out.base_acc.at[idx].set(p.base_acc),
             n_frames=out.n_frames,
             overflow=out.overflow.at[idx].set(p.overflow),
-            inexact=out.inexact.at[idx].set(p.inexact))
+            inexact=out.inexact.at[idx].set(p.inexact),
+            depth=out.depth + p.depth)
     return out
 
 
@@ -772,7 +776,8 @@ def _round_step(spec: EngineSpec, params: EngineParams,
                 ts_st_est=(st_eff if st_eff is not None
                            else jnp.asarray(spec.planner.server_time, dtype=dt)))
         ys = RoundTrace(off_counts=off_counts, miss_counts=miss_counts,
-                        correct=correct_r, lat=lat, **extras)
+                        correct=correct_r, lat=lat, plan_depth=plan.depth,
+                        **extras)
         return out, ys
 
 

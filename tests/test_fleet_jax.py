@@ -150,6 +150,107 @@ def test_planner_parity_heterogeneous():
             assert_fleet_equal(rn.state, rj.state)
 
 
+# --------------------------------------------------------------------- #
+# the planners' backlog walk stops at the deepest live backlog
+# --------------------------------------------------------------------- #
+
+DEPTH_PLANNERS = ("cbo", "cbo-split", "threshold", "greedy-rate")
+# "mix" stays below the pad, so the walk is really cut short; "full"
+# puts one stream at L, so the bound is the pad itself
+DEPTH_LENGTHS = ("empty", "zero-one", "mix", "full")
+
+
+def _depth_spec(planner, L):
+    from _diff import canonical_actions
+    from repro.core.netsim import payload_sizes, png_size_model
+    from repro.policy.fleet_jax import spec_for_policy
+    from repro.policy.registry import make_policy
+
+    kind = "cbo" if planner == "cbo-split" else planner
+    return spec_for_policy(
+        make_policy(kind, max_backlog=L),
+        sizes=payload_sizes(png_size_model, np.asarray((4, 8))),
+        acc_server=(0.7, 0.99), deadline=0.2, latency=0.05, server_time=0.037,
+        actions=canonical_actions() if planner == "cbo-split" else None)
+
+
+def _depth_fleet(lengths, S, L, seed):
+    """A padded fleet whose backlog lengths follow ``lengths``: arrivals on
+    the 1/128 grid (a backlog spans under the deadline, so the frontier
+    DP has feasible work at every depth), empty streams planned at +inf
+    as the compiled round plans them."""
+    import jax.numpy as jnp
+
+    from repro.policy.fleet_jax import pad_fleet
+
+    rng = np.random.default_rng(seed)
+    lens = {"empty": np.zeros(S, dtype=int),
+            "zero-one": np.r_[0, 1, rng.integers(0, 2, size=S - 2)],
+            "mix": np.r_[0, 1, rng.integers(0, L, size=S - 2)],
+            "full": np.r_[0, 1, rng.integers(0, L, size=S - 3), L]}[lengths]
+    t0 = rng.integers(0, 64, size=S) / 32.0
+    stream = np.repeat(np.arange(S), lens)
+    pos = np.concatenate([np.arange(n) for n in lens]) if lens.sum() else np.zeros(0)
+    arrival = t0[stream] + pos / 128.0
+    conf = rng.uniform(0.05, 0.95, size=lens.sum())
+    now = np.where(lens > 0, t0 + lens / 128.0, np.inf)
+    bw = rng.uniform(2e5, 1e7, size=S)
+    fleet = pad_fleet(arrival, conf, lens, L)
+    return fleet, jnp.asarray(now, jnp.float32), jnp.asarray(bw, jnp.float32), lens
+
+
+@pytest.mark.parametrize("lengths", DEPTH_LENGTHS)
+@pytest.mark.parametrize("planner", DEPTH_PLANNERS)
+def test_plan_depth_bound_matches_full_walk(planner, lengths):
+    """``plan_fleet`` walks the deepest live backlog, not the pad; every
+    ``PlanOut`` field equals the full ``L`` walk's bit for bit."""
+    import jax
+
+    from repro.policy import fleet_jax as fj
+
+    S, L = 7, 12
+    spec = _depth_spec(planner, L)
+    bounded = jax.jit(lambda f, now, bw: fj.plan_fleet(spec, f, now, bw))
+    full = jax.jit(lambda f, now, bw, d: fj._plan_to_depth(spec, f, now, bw, None, d))
+    for seed in range(3):
+        fleet, now, bw, lens = _depth_fleet(lengths, S, L, 9100 + seed)
+        got = bounded(fleet, now, bw)
+        ref = full(fleet, now, bw, L)
+        assert int(got.depth) == lens.max() and int(ref.depth) == L
+        assert lengths == "empty" or np.any(np.asarray(ref.dec) >= 0)
+        for k in fj.PlanOut._fields:
+            if k != "depth":
+                assert np.array_equal(np.asarray(getattr(got, k)),
+                                      np.asarray(getattr(ref, k))), (
+                    f"{planner}/{lengths} seed={seed}: {k}")
+
+
+@pytest.mark.parametrize("policy", ["cbo", "mixed"])
+def test_round_reports_plan_steps(policy):
+    """The compiled round reports the planners' walk: ``plan_steps`` sums
+    each round's deepest pruned backlog (per policy group), 0 in the first
+    round, where every backlog is empty; ``plan_steps_padded`` is R·L a
+    planner walk."""
+    from repro.obs import Telemetry
+    from repro.serving.synthetic import synthetic_streams
+
+    mix = ("cbo", "threshold", "greedy-rate")
+    pol = "cbo" if policy == "cbo" else (lambda i: mix[i % len(mix)])
+    S = 6
+    imgs, labels = synthetic_streams(S, 64, seed=4)
+    tel = Telemetry(record=False, profile=True)
+    srv, _ = make_server("jax", S=S, policy=pol, telemetry=tel)
+    recs = []
+    srv.round_hook = recs.append
+    srv.process_streams(imgs, labels)
+    groups = [np.asarray(ss) for _, ss in srv.fleet.groups]
+    L = max(p.max_backlog for p, _ in srv.fleet.groups)
+    depths = [sum(int(r["n_frames"][ss].max()) for ss in groups) for r in recs]
+    assert depths[0] == 0 and max(depths) > 0
+    assert tel.profiler.counters["plan_steps"] == sum(depths)
+    assert tel.profiler.counters["plan_steps_padded"] == len(recs) * L * len(groups)
+
+
 def test_runner_backend_validation():
     from repro.core.netsim import png_size_model
     from repro.policy.fleet import FleetRunner
