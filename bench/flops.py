@@ -1,7 +1,12 @@
-"""Operations and bytes the served work needs, computed from shapes.
+"""Operations and bytes the served work needs, computed from shapes, and
+the table of the chips' published peaks.
 
 These are the yardstick for ``tier_mfu`` and ``calib_gate_roofline``: what
-the algorithm has to do, whatever implements it.
+the algorithm has to do, whatever implements it.  ``tier_mfu`` takes a
+configuration's FLOPs from its tiers module's ``tier_flops(conf)`` hook
+(``bench/harness.py``): ``resnet_tiers`` returns ``resnet_forward_flops``;
+another architecture's count lives beside its own tiers module, so that
+adding it edits no file here.
 """
 from __future__ import annotations
 

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.flops import resnet_forward_flops
 from bench.gen import video
 from bench.ref import resnet as ref
 
@@ -101,6 +102,14 @@ def build(conf: dict, traffic: dict, seed: int, aside):
     return ResNetTiers(conf, traffic, seed, aside)
 
 
+def tier_flops(conf: dict) -> tuple[int, int]:
+    """FLOPs of one fast and one slow forward at the configuration's input
+    size: the same network, so the same count (the fast tier's int4 weights
+    are dequantized and run as the slow tier's are)."""
+    f = resnet_forward_flops(conf["img_res"], conf["depths"], conf["width"], conf["n_classes"])
+    return f, f
+
+
 class ResNetTiers:
     """Both tiers, their calibration and the traffic's frames and labels."""
 
@@ -154,6 +163,8 @@ class ResNetTiers:
         fast_params = {**fast_params, "head": {**fast_params["head"], "b": fast_bias}}
         self.fast = lambda x: tier_fast(fast_params, x)
         self.slow = lambda x: tier_slow(self.params, x)
+        self._programs = {"jit_tier_fast": (tier_fast, fast_params),
+                          "jit_tier_slow": (tier_slow, self.params)}
 
         # calibration split: one round's worth of frames
         calib = jax.block_until_ready(gen(k_platt, n_streams=self.batch // 32 or 1, n_frames=32)
@@ -167,6 +178,14 @@ class ResNetTiers:
         self.agreement = float(agree.mean())
         if not 0.0 < self.agreement < 1.0:
             raise RuntimeError(f"fast/slow agreement {self.agreement}: nothing would escalate")
+
+    def program_texts(self) -> dict:
+        """Each tier program's compiled text at one round's input (S x B
+        float32 frames at the configuration's size), as the bridge calls
+        it: the program the trace ran, so a persistent-cache hit."""
+        x = jax.ShapeDtypeStruct((self.batch, self.res, self.res, 3), jnp.float32)
+        return {name: fn.lower(params, x).compile().as_text()
+                for name, (fn, params) in self._programs.items()}
 
     def segment(self, i: int):
         """Segment ``i``'s (S, N, H, W, 3) host frames and (S, N) labels."""

@@ -8,9 +8,54 @@ backlogs, so a segment is its unit of work).  One client, closed loop:
 the next segment is handed over when the previous one returns.  Frames
 stay in host numpy arrays, as the server receives them.
 
-Everything a configuration or a traffic mix sets comes from its file; the
-configuration's ``tiers`` names the module under ``bench/gen`` whose
-``build(conf, traffic, seed, aside)`` makes its tiers and frames.
+Everything a configuration or a traffic mix sets comes from its file
+(``bench/configs/<config>.json``, ``bench/traffic/<traffic>.json``); the
+configuration's ``tiers`` names the module under ``bench/gen`` that makes
+its tiers and frames.  A new configuration enters by new files and
+appended ``BENCHMARK.json`` entries alone, and its cell reports every
+per-layer metric whose reader finds what it reads, if its tiers module
+keeps to this contract:
+
+``build(conf, traffic, seed, aside)`` returns the system, an object with
+
+* ``fast``, ``slow``: the tiers the server calls, each ``(n, H, W, 3)``
+  float32 frames to ``(n, classes)`` logits, each through one jitted
+  program named ``tier_fast`` or ``tier_slow`` (so the trace holds
+  ``jit_tier_fast`` and ``jit_tier_slow``, which ``tier_device_ms`` reads);
+* ``platt``: Platt's ``(a, b)`` for the fast tier's confidence, or None for
+  the plain max-softmax;
+* ``segment(i)``: segment ``i``'s ``(S, N, H, W, 3)`` host frames and
+  ``(S, N)`` labels, the same for the same seed;
+* ``ref_fast(x)``, ``ref_slow(x, res)``: the plain reference's logits, at
+  the configuration's stated precision, the slow tier's on frames degraded
+  to the rung ``res`` (``bench/check.py``); the reference imports nothing
+  of the program;
+* ``control_tiers()``: ``(fast, slow)``, the reference one precision step
+  below the stated one, in the program's place; ``control_conf(logits)``:
+  the gate's confidence at that precision;
+* ``program_texts()`` (optional): ``{"jit_tier_fast": text,
+  "jit_tier_slow": text}``, each tier program's compiled text at one
+  round's input (S x B frames), so that a reader can split its device time
+  by the model's own ``jax.named_scope`` names
+  (``bench/program_trace.py::program_texts``).
+
+Work that makes what the check compares against (labels, calibration)
+runs inside ``aside``, which set-up leaves out.  The module may define
+``tier_flops(conf) -> (fast, slow)``, the FLOPs of one forward of each
+tier at the configuration's input size, which ``tier_mfu`` reads; without
+it ``tier_mfu`` reads nothing.  A configuration with ``use_fused: true``
+runs the fused calibrate+gate kernel (``jit_calib_gate``, read by
+``calib_gate_roofline``) and needs ``platt``.  The round
+(``round_device_ms``, ``plan_device_ms``, ``plan_walk_share``), the bridge
+(``fold_ms``, ``bridge_idle_ms``, ``slow_useful_ratio``), ``idle_share``
+and ``compile_s`` need nothing more than serving through this harness.
+
+No per-layer metric lists its cells, so a traced line has to carry every
+one of them.  A tiers module that keeps only part of this contract, as
+``synthetic`` does (closed-form tiers, no fused gate, no ``tier_flops``),
+leaves ``tier_device_ms``, ``tier_mfu`` and ``calib_gate_roofline``
+unread, and its cell cannot enter by new files alone: those three entries
+first need a ``workloads`` list of the cells that keep the contract.
 """
 from __future__ import annotations
 

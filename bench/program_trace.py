@@ -1,4 +1,4 @@
-"""The program's own spans and stage scopes in a device trace.
+"""The program's own spans and name scopes in a device trace.
 
 The served path names its host work with ``repro.*`` spans (the program's
 ``PhaseProfiler``, under ``Telemetry(profile=True)``) and each stage of the
@@ -12,16 +12,21 @@ This module reduces a profiler trace against both:
   compiled program (``module_op_s``: the ``XLA Ops`` of the ``XLA Modules``
   event they run in, so that same-named ops of two programs stay apart).
 * ``scope_map``: TPU op events carry no scope, only their HLO instruction
-  name, so the compiled round's text maps each instruction to the
-  ``round.<stage>`` in its ``metadata={op_name=...}``.
-* ``program_trace``: the trace both of the readers ``plan_device_ms`` and
-  ``bridge_idle_ms`` need.  The traced window's own trace is reduced by
-  ``bench/trace.py`` and deleted before the readers run, and that reduction
-  keeps neither these spans nor op times per program; so after the window
-  this traces ``trace_segments`` more segments of the same served path, the
-  profiler set as for the window, and compiles the round once more (a cache
-  hit) for its text.  A program that opens no ``repro.*`` spans gets no
-  such trace.
+  name, so a compiled program's text maps each instruction to the name
+  scope in its ``metadata={op_name=...}`` that matches a pattern: the
+  round's ``round.<stage>`` (``ROUND_SCOPE``) by default, or any other,
+  such as a model's own ``jax.named_scope`` inside a tier program.
+* ``program_trace``: the trace that the readers of these spans and scopes
+  need.  The traced window's own trace is reduced by ``bench/trace.py`` and
+  deleted before the readers run, and that reduction keeps neither these
+  spans nor op times per program; so after the window this traces
+  ``trace_segments`` more segments of the same served path, the profiler
+  set as for the window, and compiles the round once more (a cache hit) for
+  its text.  ``program_texts`` gives that text beside the tier programs'
+  (the tiers object's ``program_texts()``, ``bench/harness.py``, compiled
+  only when a reader asks), and ``scope_seconds`` splits the tier
+  programs' device time by a model's own name scopes.  A program that
+  opens no ``repro.*`` spans gets no such trace.
 """
 from __future__ import annotations
 
@@ -34,7 +39,8 @@ from bench.trace import DEVICE_PLANE, MODULE_ID, WINDOW_SPAN, _clip, _self_times
 
 PREFIXES = ("bench.", "repro.")
 PROGRAM_PREFIX = "repro."
-STAGE = re.compile(r"^round\.[A-Za-z_]+$")
+ROUND_SCOPE = r"^round\.[A-Za-z_]+$"
+MODULE_NAME = re.compile(r"^HloModule ([^\s,]+)")
 INSTRUCTION = re.compile(r'^\s*(?:ROOT\s+)?%([^\s=]+)\s*=.*\bmetadata=\{[^}]*\bop_name="([^"]*)"')
 
 
@@ -153,30 +159,35 @@ def reduce_file(path: str) -> ProgramReduction:
     return reduce_planes(ProfileData.from_file(path).planes)
 
 
-def _instruction_stages(text: str):
-    """(instruction name, the stages its ``op_name`` names) per instruction
-    of a compiled module's text that carries an ``op_name``."""
+def _instruction_scopes(text: str, pattern: str):
+    """(instruction name, the scopes matching ``pattern`` that its
+    ``op_name`` names) per instruction of a compiled module's text that
+    carries an ``op_name``."""
+    scope = re.compile(pattern)
     for line in text.splitlines():
         m = INSTRUCTION.match(line)
         if m:
-            yield m.group(1), {part for part in m.group(2).split("/") if STAGE.match(part)}
+            yield m.group(1), {part for part in m.group(2).split("/") if scope.match(part)}
 
 
-def scope_map(text: str) -> dict:
-    """``{instruction name: "round.<stage>"}`` for each instruction whose
-    ``op_name`` holds exactly one stage."""
-    return {name: next(iter(st)) for name, st in _instruction_stages(text) if len(st) == 1}
+def scope_map(text: str, pattern: str = ROUND_SCOPE) -> dict:
+    """``{instruction name: scope}`` for each instruction whose ``op_name``
+    holds exactly one scope matching ``pattern`` (one path component, such
+    as ``round.plan`` or ``swin.attn``)."""
+    return {name: next(iter(sc)) for name, sc in _instruction_scopes(text, pattern)
+            if len(sc) == 1}
 
 
 def scoped_instructions(text: str) -> tuple[int, int]:
-    """(instructions whose ``op_name`` names any stage, those that name
-    exactly one)."""
-    stages = [len(st) for _, st in _instruction_stages(text) if st]
+    """(instructions whose ``op_name`` names any stage of the round, those
+    that name exactly one)."""
+    stages = [len(st) for _, st in _instruction_scopes(text, ROUND_SCOPE) if st]
     return len(stages), stages.count(1)
 
 
 def stage_seconds(op_s: dict, smap: dict) -> dict:
-    """Op self seconds summed by stage; ops of no stage under ``""``."""
+    """Op self seconds (one program's, from ``module_op_s``) summed by the
+    scope ``smap`` gives each op; ops of no scope under ``""``."""
     out = {}
     for name, secs in op_s.items():
         stage = smap.get(name, "")
@@ -246,9 +257,10 @@ def trace_segments(conf, traffic, system, pool, n_segments, trace_dir):
 
 
 def program_trace(ctx):
-    """``(ProgramReduction, scope map, segments)`` of a trace taken after
-    the window, held in ``ctx`` for every reader; None where the program
-    opens no spans or the run holds no system to serve."""
+    """``(ProgramReduction, the round's scope map, segments)`` of a trace
+    taken after the window, held in ``ctx`` for every reader; None where
+    the program opens no spans or the run holds no system to serve.  The
+    round's compiled text goes to ``ctx["round_text"]`` (``program_texts``)."""
     if "program_trace" not in ctx:
         ctx["program_trace"] = None
         out = ctx["out"]
@@ -267,5 +279,45 @@ def program_trace(ctx):
                 red = reduce_file(find_xplane(d))
             finally:
                 shutil.rmtree(d, ignore_errors=True)
-            ctx["program_trace"] = (red, scope_map(text), n)
+            ctx["round_text"] = text
+            ctx["program_trace"] = (red, scope_map(text, ROUND_SCOPE), n)
     return ctx["program_trace"]
+
+
+def program_texts(ctx) -> dict:
+    """``{program name: compiled text}`` of the programs ``program_trace``
+    traced: the round's (``jit_run``) and, where the tiers object hands them
+    over, the tier programs' at one round's input, compiled (a cache hit)
+    only when a reader first asks; empty where there is no such trace.
+    Each text is keyed by the name its ``HloModule`` line gives.  On the
+    TPU two tier programs that compile alike (one model in both tiers) run
+    as one executable, and the trace shows both tiers' ops under one of the
+    two names, so ``module_op_s`` may hold ``jit_tier_fast`` alone;
+    ``scope_seconds`` reads whichever is there."""
+    if program_trace(ctx) is None:
+        return {}
+    if "program_texts" not in ctx:
+        tiers = getattr(ctx["out"]["system"], "program_texts", None)
+        texts = [ctx["round_text"], *(tiers().values() if tiers is not None else ())]
+        ctx["program_texts"] = {MODULE_NAME.match(t).group(1): t for t in texts}
+    return ctx["program_texts"]
+
+
+def scope_seconds(ctx, pattern: str):
+    """Device seconds by name scope (``pattern``, as for ``scope_map``) of
+    the traced tier programs (``jit_tier_*``), each program's ops mapped by
+    its own compiled text; ops of no scope under ``""``.  None where there
+    is no trace or no tier program ran."""
+    got = program_trace(ctx)
+    if got is None:
+        return None
+    red = got[0]
+    ran = {name: text for name, text in program_texts(ctx).items()
+           if name.startswith("jit_tier_") and red.module_op_s.get(name)}
+    if not ran:
+        return None
+    out = {}
+    for name, text in ran.items():
+        for scope, secs in stage_seconds(red.module_op_s[name], scope_map(text, pattern)).items():
+            out[scope] = out.get(scope, 0.0) + secs
+    return out

@@ -209,8 +209,12 @@ def test_slow_useful_ratio(profiler, want):
 
 
 def test_new_metrics_are_listed_for_the_cell():
+    """The readers of this module's trace are listed by name, wherever in
+    ``per_layer``, and no entry names a cell ``workloads`` lacks."""
     bench = harness.load_json("BENCHMARK.json")
-    names = [m["name"] for m in bench["per_layer"]]
-    assert names[-3:] == ["plan_device_ms", "bridge_idle_ms", "slow_useful_ratio"]
-    for m in bench["per_layer"][-3:]:
-        assert m["workloads"] == ["resnet50-paper.s16"] and m["moves"] == "frames_per_s"
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    for name in ("plan_device_ms", "bridge_idle_ms", "slow_useful_ratio"):
+        assert by_name[name]["moves"] == "frames_per_s"
+    cells = {w["name"] for w in bench["workloads"]}
+    for m in bench["per_layer"]:
+        assert set(m.get("workloads", cells)) <= cells, m["name"]
